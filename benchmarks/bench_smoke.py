@@ -861,6 +861,8 @@ def main() -> None:
                     help="also run the wall-clock timing harness (zipfian "
                          "R=64, W in {1,4}; reported, never gated)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     current = collect(wallclock=args.wallclock)
     with open(args.out, "w") as f:

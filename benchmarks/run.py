@@ -50,6 +50,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     ap.add_argument("--roofline-dir", default="experiments/dryrun")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks.paper_benches import ALL
 

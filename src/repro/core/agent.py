@@ -22,7 +22,7 @@ from typing import NamedTuple, Tuple
 import jax.numpy as jnp
 
 from .messages import MsgType
-from .protocol import DenseTables, LocalOp
+from .protocol import DenseTables, LocalOp, lookup
 from .states import RemoteState
 
 
@@ -60,10 +60,6 @@ def make_agent(n_lines: int, block: int, dtype=jnp.float32) -> AgentState:
     )
 
 
-def _jt(table, *idx):
-    return jnp.asarray(table)[idx]
-
-
 def submit(tables: DenseTables, st: AgentState, op: jnp.ndarray,
            value: jnp.ndarray
            ) -> Tuple[AgentState, jnp.ndarray, jnp.ndarray, jnp.ndarray,
@@ -92,10 +88,10 @@ def submit(tables: DenseTables, st: AgentState, op: jnp.ndarray,
     wants = o != int(LocalOp.NOP)
     accepted = wants & idle
 
-    new_state = _jt(tables.loc_new_state, o, rs)
-    request = _jt(tables.loc_request, o, rs)
-    req_dirty = _jt(tables.loc_req_dirty, o, rs)
-    hit = _jt(tables.loc_hit, o, rs)
+    new_state = lookup(tables.loc_new_state, o, rs)
+    request = lookup(tables.loc_request, o, rs)
+    req_dirty = lookup(tables.loc_req_dirty, o, rs)
+    hit = lookup(tables.loc_hit, o, rs)
 
     is_hit = accepted & hit
     is_miss = accepted & ~hit
@@ -150,7 +146,7 @@ def on_response(tables: DenseTables, st: AgentState, active: jnp.ndarray,
     """
     req = st.pending_req.astype(jnp.int32)
     rm = resp.astype(jnp.int32)
-    new_state = _jt(tables.resp_new_state, req, rm).astype(jnp.int32)
+    new_state = lookup(tables.resp_new_state, req, rm).astype(jnp.int32)
     legal = new_state >= 0
     do = active & legal
     nack = active & (rm == int(MsgType.RESP_NACK))
@@ -190,10 +186,10 @@ def on_home_msg(tables: DenseTables, st: AgentState, active: jnp.ndarray,
     """
     m = msg.astype(jnp.int32)
     rs = st.remote_state.astype(jnp.int32)
-    new_state = _jt(tables.rem_new_state, m, rs)
-    resp = _jt(tables.rem_resp, m, rs)
-    resp_dirty = _jt(tables.rem_resp_dirty, m, rs)
-    legal = _jt(tables.rem_legal, m, rs)
+    new_state = lookup(tables.rem_new_state, m, rs)
+    resp = lookup(tables.rem_resp, m, rs)
+    resp_dirty = lookup(tables.rem_resp_dirty, m, rs)
+    legal = lookup(tables.rem_legal, m, rs)
     do = active & legal
     new = st._replace(
         remote_state=jnp.where(do, new_state.astype(jnp.int8),

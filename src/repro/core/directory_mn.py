@@ -48,9 +48,10 @@ from __future__ import annotations
 from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from .messages import MsgType
-from .protocol import MN_REQUEST_VIEW, DenseTablesMN, MnAbsorb
+from .protocol import MN_REQUEST_VIEW, DenseTablesMN, MnAbsorb, lookup
 from .states import HomeState, RemoteView
 
 #: Plane indices of the packed ``[2, L, W]`` view array.
@@ -162,22 +163,21 @@ def view_of(st: DirectoryMNState, node: jnp.ndarray) -> jnp.ndarray:
     return _take_remote(st.view, node).astype(jnp.int32)
 
 
-def _jt(table, *idx):
-    return jnp.asarray(table)[idx]
-
-
 def _take_remote(arr: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarray:
-    """Gather ``arr[..., node[l], l]`` — one remote's row per line.
+    """``arr[..., node[l], l]`` — one remote's row per line.
 
     ``arr`` is ``[..., R, L]`` (or ``[..., R, L, B]``), ``node`` is
-    ``[..., L]``; the gather runs along the remote axis so it is the same
-    single op for the flat and the home-batched layouts."""
-    if arr.ndim == node.ndim + 2:            # [..., R, L, B] payloads
-        idx = node[..., None, :, None]
-        return jnp.take_along_axis(
-            arr, jnp.broadcast_to(idx, idx.shape[:-1] + arr.shape[-1:]),
-            axis=-3)[..., 0, :, :]
-    return jnp.take_along_axis(arr, node[..., None, :], axis=-2)[..., 0, :]
+    ``[..., L]`` with every id in ``[0, R)``.  A select chain over the
+    remote axis, not a gather: it is one fused pass over the plane on a
+    TPU, where a per-line gather is an indexed load per element.  Same
+    op for the flat and the home-batched layouts."""
+    payload = arr.ndim == node.ndim + 2
+    sel = node[..., None] if payload else node
+    rows = jnp.moveaxis(arr, -3 if payload else -2, 0)
+    out = rows[0]
+    for r in range(1, rows.shape[0]):
+        out = jnp.where(sel == r, rows[r], out)
+    return out
 
 
 def home_value(st: DirectoryMNState) -> jnp.ndarray:
@@ -249,9 +249,9 @@ def absorb(tables: DenseTablesMN, st: DirectoryMNState,
 
     hs = st.home_state.astype(jnp.int32)
     one = jnp.ones_like(hs)
-    new_home = _jt(tables.absorb_new_home, d_kind, one, hs)
-    to_back = _jt(tables.absorb_to_backing, d_kind, one, hs) & any_dirty
-    to_buf = _jt(tables.absorb_to_homebuf, d_kind, one, hs) & any_dirty
+    new_home = lookup(tables.absorb_new_home, d_kind, one, hs)
+    to_back = lookup(tables.absorb_to_backing, d_kind, one, hs) & any_dirty
+    to_buf = lookup(tables.absorb_to_homebuf, d_kind, one, hs) & any_dirty
 
     home_state = jnp.where(any_dirty, new_home.astype(jnp.int8),
                            st.home_state)
@@ -372,17 +372,17 @@ def grant(tables: DenseTablesMN, st: DirectoryMNState, active: jnp.ndarray,
     hs = st.home_state.astype(jnp.int32)
     req_view = view_of(st, node)                          # requester's
 
-    want_view = _jt(jnp.asarray(
-        [MN_REQUEST_VIEW.get(i, 0) for i in range(16)], jnp.int32), m)
-    legal = _jt(tables.grant_legal, m, hs) & (req_view == want_view)
+    want_view = lookup(np.asarray(
+        [MN_REQUEST_VIEW.get(i, 0) for i in range(16)], np.int32), m)
+    legal = lookup(tables.grant_legal, m, hs) & (req_view == want_view)
     is_upgrade_race = active & (m == int(MsgType.REQ_UPGRADE)) & \
         (req_view != int(RemoteView.S))
     do = active & legal
 
     val = home_value(st)                                  # serve-then-move
-    new_home = _jt(tables.grant_new_home, m, hs)
-    resp = _jt(tables.grant_resp, m, hs)
-    wb = _jt(tables.grant_wb, m, hs)
+    new_home = lookup(tables.grant_new_home, m, hs)
+    resp = lookup(tables.grant_resp, m, hs)
+    wb = lookup(tables.grant_wb, m, hs)
 
     if tables.stateless_home:
         # single joint state I*: serve the data, record nothing.
@@ -391,7 +391,7 @@ def grant(tables: DenseTablesMN, st: DirectoryMNState, active: jnp.ndarray,
         backing = jnp.where((do & wb)[..., None], st.home_buf, st.backing)
         home_state = jnp.where(do, new_home.astype(jnp.int8),
                                st.home_state)
-        new_view = _jt(tables.grant_view, m)
+        new_view = lookup(tables.grant_view, m)
         if st.view.dtype == jnp.uint32:
             # set/clear exactly the requester's bit on granting lines —
             # the [..., R, L] one-hot compare becomes two word updates.

@@ -28,7 +28,7 @@ from . import agent as ag
 from . import directory as dr
 from . import transport as tp
 from .messages import MsgType
-from .protocol import FULL, MINIMAL, DenseTables, LocalOp
+from .protocol import FULL, MINIMAL, DenseTables, LocalOp, lookup
 
 
 class EngineState(NamedTuple):
@@ -229,7 +229,7 @@ def stall_unready_ops(tables: DenseTables, ch_req, eff_op: jnp.ndarray,
     """
     o = eff_op.astype(jnp.int32)
     rs = remote_state.astype(jnp.int32)
-    req_of = jnp.asarray(tables.loc_request)[o, rs].astype(jnp.int8)
+    req_of = lookup(tables.loc_request, o, rs).astype(jnp.int8)
     would_emit = req_of != jnp.int8(int(MsgType.NOP))
     _, acc_pre = tp.submit(ch_req, tp.CLASS_REMOTE_REQ, would_emit, req_of,
                            jnp.zeros(would_emit.shape, bool), op_val,
@@ -332,7 +332,7 @@ def step(tables: DenseTables, st: EngineState,
     # load hits retire immediately.
     o = eff_op.astype(jnp.int32)
     rs = astate.remote_state.astype(jnp.int32)
-    hit = jnp.asarray(tables.loc_hit)[o, rs]
+    hit = lookup(tables.loc_hit, o, rs)
     load_hit = accepted & hit & (o == int(LocalOp.LOAD))
     load_done = load_done | load_hit
     load_val = jnp.where(load_hit[:, None], astate2.cache, load_val)

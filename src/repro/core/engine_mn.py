@@ -60,7 +60,7 @@ from .engine import _count
 from .messages import MAX_NODE, MsgType
 from .protocol import (ENHANCED_MESI, FULL_MOESI, DenseTables,
                        DenseTablesMN, LocalOp, MnAbsorb, ProtocolSubset,
-                       bake_mn, mn_tables)
+                       bake_mn, lookup, mn_tables)
 from .states import RemoteView
 
 #: Remote-count ceiling, DERIVED from the EWF node-id field width — widening
@@ -413,10 +413,10 @@ def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
     # (``home_group = 1`` degenerates to global parity, bit-identical).
     par = (lines & 1) if home_group is None \
         else ((lines // home_group) & 1)
-    dly_req = delays[2 * tp.CLASS_REMOTE_REQ + par]
-    dly_resp = delays[2 * tp.CLASS_HOME_RESP + par]
-    dly_hreq = delays[2 * tp.CLASS_HOME_REQ + par]
-    dly_hresp = delays[2 * tp.CLASS_REMOTE_RESP + par]
+    dly_req = tp.vc_value(delays, par, tp.CLASS_REMOTE_REQ)
+    dly_resp = tp.vc_value(delays, par, tp.CLASS_HOME_RESP)
+    dly_hreq = tp.vc_value(delays, par, tp.CLASS_HOME_REQ)
+    dly_hresp = tp.vc_value(delays, par, tp.CLASS_REMOTE_RESP)
 
     # accumulate new home-side wants.
     want_read = st.want_read | want_read
@@ -694,7 +694,7 @@ def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
     # mask ops outside the subset's MN envelope (DEMOTE always — see the
     # module docstring — plus whatever the subset's guarantee excludes;
     # the public APIs reject such programs loudly BEFORE they get here).
-    op_ok = jnp.asarray(tables_mn.op_ok)[eff_op.astype(jnp.int32)]
+    op_ok = lookup(tables_mn.op_ok, eff_op)
     eff_op = jnp.where(op_ok, eff_op, jnp.int8(int(LocalOp.NOP)))
     # An op that would emit a message stalls until the transport CAN take
     # it (slot + credit) — the dirty-eviction drop guard of
@@ -704,7 +704,7 @@ def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
     # acceptance and the channel write needs no second ranking.
     o = eff_op.astype(jnp.int32)
     rs = agents.remote_state.astype(jnp.int32)
-    req_of = jnp.asarray(tables.loc_request)[o, rs].astype(jnp.int8)
+    req_of = lookup(tables.loc_request, o, rs).astype(jnp.int8)
     would_emit = req_of != nop
     acc_pre = tp.credit_accept(ch_req, tp.CLASS_REMOTE_REQ,
                                would_emit & (ch_req.msg == nop), credits,
@@ -717,7 +717,7 @@ def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
     ch_req = tp.place(ch_req, emit != nop, emit, req_dirty, req_pay)
     # load hits retire immediately.
     o = eff_op.astype(jnp.int32)
-    hit = jnp.asarray(tables.loc_hit)[o, rs]
+    hit = lookup(tables.loc_hit, o, rs)
     load_hit = accepted & hit & (o == int(LocalOp.LOAD))
     load_done = load_done | load_hit
     load_val = jnp.where(load_hit[..., None], agents2.cache, load_val)

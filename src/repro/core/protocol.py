@@ -28,6 +28,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, FrozenSet, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .messages import MsgType
@@ -300,6 +302,30 @@ N_MSG = 16
 N_HOME = 5
 N_VIEW = 3
 N_REMOTE = 4
+
+
+def lookup(table: np.ndarray, *idx) -> jnp.ndarray:
+    """``table[idx]`` for a small, static protocol table, as one
+    compare-select per nonzero entry.
+
+    On a TPU a gather from a table of a few dozen entries is an indexed
+    load per element; at 48 remotes x 131,072 lines those lookups were
+    most of an engine step, while the select chain is plain vector work
+    that fuses.  Index semantics are a gather's: negative indices wrap,
+    out-of-range ones clamp.
+    """
+    t = np.asarray(table)
+    flat = None
+    for i, d in zip(idx, t.shape):
+        i = jnp.asarray(i, jnp.int32)
+        i = jnp.clip(jnp.where(i < 0, i + d, i), 0, d - 1)
+        flat = i if flat is None else flat * d + i
+    dt = jax.dtypes.canonicalize_dtype(t.dtype)
+    out = jnp.zeros(jnp.shape(flat), dt)
+    for k, v in enumerate(t.reshape(-1)):
+        if v:
+            out = jnp.where(flat == k, jnp.asarray(v, dt), out)
+    return out
 
 
 @dataclasses.dataclass(frozen=True)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..nmp.dfa import dfa_select
 from ..nmp.kvstore import KVStore, fib_hash
@@ -54,10 +53,10 @@ def pushdown_select(mesh: Mesh, axis: str, capacity: int,
         packs, counts = _gather_matches(axis, packed, count)
         return packs, counts
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(axis, None), P(), P()),
-                   out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(axis, None), P(), P()),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     packs, counts = jax.jit(fn)(table, jnp.asarray(x, table.dtype),
                                 jnp.asarray(y, table.dtype))
     return PushdownResult(packs, counts, counts.sum())
@@ -74,8 +73,8 @@ def pushdown_regex(mesh: Mesh, axis: str, capacity: int, dfa: DFA,
         packs, counts = _gather_matches(axis, packed, count)
         return packs, counts
 
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(P(axis, None),),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(axis, None),),
+                       out_specs=(P(), P()), check_vma=False)
     packs, counts = jax.jit(fn)(table)
     return PushdownResult(packs, counts, counts.sum())
 
@@ -162,11 +161,11 @@ def pushdown_lookup(mesh: Mesh, axis: str, kvs: ShardedKVS,
                 jax.lax.psum(found.astype(jnp.int32), axis) > 0,
                 jax.lax.psum(steps, axis))
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(axis, None), P(axis, None),
-                             P(axis, None, None), P(axis, None), P()),
-                   out_specs=(P(), P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(axis, None), P(axis, None),
+                                 P(axis, None, None), P(axis, None), P()),
+                       out_specs=(P(), P(), P()),
+                       check_vma=False)
     return jax.jit(fn, static_argnums=())(kvs.heads, kvs.keys, kvs.values,
                                           kvs.nxt,
                                           queries.astype(jnp.uint32))

@@ -63,6 +63,13 @@ def vc_of(line, msg_class):
     return msg_class * 2 + (line & 1)
 
 
+def vc_value(values: jnp.ndarray, line, msg_class: int) -> jnp.ndarray:
+    """``values[vc_of(line, msg_class)]`` as a parity select — a per-line
+    gather is an indexed load per element on a TPU."""
+    return jnp.where((line & 1) == 1, values[2 * msg_class + 1],
+                     values[2 * msg_class])
+
+
 class Channel(NamedTuple):
     """One direction of per-line in-flight messages (struct-of-arrays).
 
@@ -150,7 +157,7 @@ def credit_accept(ch: Channel, msg_class: int, cand: jnp.ndarray,
         rank_o = jnp.cumsum(c_o, axis=-1) - c_o    # candidates before me
         rank_e = jnp.cumsum(c_e, axis=-1) - c_e
         occ_rank = jnp.where(odd, occ_o + rank_o, occ_e + rank_e)
-    vc_credit = credits[vc_of(jnp.arange(L), msg_class)]        # [L]
+    vc_credit = vc_value(credits, jnp.arange(L), msg_class)     # [L]
     return cand & (occ_rank < vc_credit)
 
 
@@ -224,7 +231,7 @@ def deliver(ch: Channel, msg_class: int, delays: jnp.ndarray,
     bodies of their fused steps.
     """
     if delay_l is None:
-        delay_l = delays[vc_of(jnp.arange(ch.msg.shape[-1]), msg_class)]
+        delay_l = vc_value(delays, jnp.arange(ch.msg.shape[-1]), msg_class)
     ready = (ch.msg != int(MsgType.NOP)) & (ch.age >= delay_l)
     freed = ch._replace(msg=jnp.where(ready, int(MsgType.NOP),
                                       ch.msg).astype(jnp.int8))

@@ -22,14 +22,24 @@ docs/perf.md), each as a Pallas kernel with its pure-jnp oracle in
 
 Everything here is integer/boolean arithmetic, so the contract with the
 refs is BIT-EXACT equality — in interpret mode on CPU (what CI runs) and
-under real Mosaic lowering on TPU.  The kernels avoid TPU-hostile
-primitives on purpose: cumulative sums become small integer matmuls
-against in-kernel iota masks (MXU-friendly), argmin becomes an
-encode/min/decode over ``score * (P+1) + p`` (exact because priorities
-are a permutation per line and ties only occur at the not-ready fill
-value, where min-of-encoding picks the lowest participant id — the same
-first-minimum rule as ``jnp.argmin``), and ``searchsorted`` becomes a
-static unrolled ``sum(lat >= edge)``.
+under real Mosaic lowering on TPU (``tests/test_tpu_compile.py`` compiles
+every kernel for a TPU v5e at R=48, L=131072).  What Mosaic accepts
+shapes the kernels:
+
+* the line axis is tiled in lane-aligned blocks (multiples of 128) and
+  no kernel holds a full-L block, so VMEM use does not grow with L.  A
+  reduction that crosses line blocks carries its partial result over an
+  "arbitrary" grid axis, in a resident output tile or a VMEM scratch;
+* no integer matmul (v5e's MXU takes none): the credit rank's in-block
+  running count is a bf16 matmul of a 0/1 plane against a 0/1 triangle,
+  accumulated in f32 — exact, since no block sum exceeds the block width;
+* no in-kernel reshape: one-hot folds compare against each type in a
+  static unroll.  Argmin becomes an encode/min/decode over
+  ``score * (P+1) + p`` (exact because priorities are a permutation per
+  line and ties only occur at the not-ready fill value, where
+  min-of-encoding picks the lowest participant id — the same
+  first-minimum rule as ``jnp.argmin``), and ``searchsorted`` becomes a
+  static unrolled ``sum(lat >= edge)``.
 
 The engine reaches these only when its ``kernel_backend`` is "pallas"
 (``REPRO_KERNEL_BACKEND`` env or ``EngineConfig.kernel_backend``); the
@@ -39,15 +49,29 @@ every committed baseline.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_LANE = 128
+#: elements per [rows, lanes] block: each int32 temporary of a kernel
+#: stays near 512 KiB, far inside the scoped VMEM of a TPU core.
+_BLOCK_ELEMS = 1 << 17
+#: row-block cap for [rows, L] planes (a multiple of every dtype's
+#: sublane tile).
+_ROW_CAP = 256
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _pad_rows(x: jnp.ndarray, mult: int):
@@ -59,53 +83,131 @@ def _pad_rows(x: jnp.ndarray, mult: int):
     return jnp.pad(x, width), n
 
 
+def _pad_to(x: jnp.ndarray, shape) -> jnp.ndarray:
+    """Zero/False-pad ``x`` up to ``shape`` at the high end of each axis
+    (padding lanes are inert in every kernel: no activity, no ready)."""
+    if tuple(x.shape) == tuple(shape):
+        return x
+    return jnp.pad(x, [(0, s - d) for s, d in zip(shape, x.shape)])
+
+
+def _row_block(rows: int) -> Tuple[int, int]:
+    """(row block, padded rows) for a ``[rows, L]`` plane."""
+    bn = min(_round_up(max(rows, 1), 8), _ROW_CAP)
+    return bn, _round_up(max(rows, 1), bn)
+
+
+def _lane_block(rows: int, L: int, cap: int = 2048) -> Tuple[int, int]:
+    """(lane block, padded L): a multiple of 128 lanes, at most ``cap``,
+    sized so ``rows x lanes`` stays within ``_BLOCK_ELEMS``."""
+    Lp = _round_up(max(L, 1), _LANE)
+    fit = max(_LANE, _BLOCK_ELEMS // max(rows, 1) // _LANE * _LANE)
+    bl = min(Lp, cap, fit)
+    return bl, _round_up(Lp, bl)
+
+
+def _params(*semantics: str):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
+
+
+def _sum_lanes(x: jnp.ndarray) -> jnp.ndarray:
+    """[bn, bl] int32 -> [bn, 1] row sums."""
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _flat_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """``[..., L]`` -> ``[rows, L]`` (rows = product of the leading axes)."""
+    return x.reshape(math.prod(x.shape[:-1]), x.shape[-1])
+
+
 # ---------------------------------------------------------------------------
 # credit_rank
 # ---------------------------------------------------------------------------
 
 
-def _credit_rank_kernel(act_ref, cand_ref, out_ref, *, L: int):
-    act = act_ref[:].astype(jnp.int32)                    # [bn, L]
-    cnd = cand_ref[:].astype(jnp.int32)
-    j = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)    # source line
-    i = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)    # ranked line
-    same = ((j & 1) == (i & 1)).astype(jnp.int32)         # same VC parity
-    earlier = same * (j < i).astype(jnp.int32)
-    # rank[n, i] = sum_j active[n, j] * same[j, i]
-    #            + sum_j cand[n, j]  * (same & j < i)[j, i]
-    # — the parity-split occupancy + exclusive running rank as two integer
-    # matmuls (exact in int32; MXU-shaped on TPU instead of a cumsum).
-    dn = (((1,), (0,)), ((), ()))
-    out_ref[:] = (
-        jax.lax.dot_general(act, same, dn,
-                            preferred_element_type=jnp.int32)
-        + jax.lax.dot_general(cnd, earlier, dn,
-                              preferred_element_type=jnp.int32))
+def _credit_rank_kernel(tri_ref, act_ref, cand_ref, out_ref,
+                        occ_o, occ_e, run_o, run_e):
+    # grid (row block, phase, line block): phase 0 folds the row's
+    # parity-split occupancy over every line block; phase 1 writes the
+    # ranks, carrying the running candidate count across line blocks.
+    phase, lb = pl.program_id(1), pl.program_id(2)
+    bn, bl = act_ref.shape
+    odd = (jax.lax.broadcasted_iota(jnp.int32, (bn, bl), 1) & 1) == 1
+
+    def parity_counts(x):                                 # [bn, _LANE] x2
+        o = _sum_lanes(jnp.where(x & odd, 1, 0))
+        e = _sum_lanes(jnp.where(x & ~odd, 1, 0))
+        return (jnp.broadcast_to(o, (bn, _LANE)),
+                jnp.broadcast_to(e, (bn, _LANE)))
+
+    @pl.when((phase == 0) & (lb == 0))
+    def _():
+        occ_o[:] = jnp.zeros_like(occ_o)
+        occ_e[:] = jnp.zeros_like(occ_e)
+
+    @pl.when(phase == 0)
+    def _():
+        o, e = parity_counts(act_ref[:])
+        occ_o[:] += o
+        occ_e[:] += e
+
+    @pl.when((phase == 1) & (lb == 0))
+    def _():
+        run_o[:] = jnp.zeros_like(run_o)
+        run_e[:] = jnp.zeros_like(run_e)
+
+    @pl.when(phase == 1)
+    def _():
+        cand = cand_ref[:]
+        # earlier same-parity candidates within the block: a 0/1 bf16
+        # plane against the 0/1 (j < i, same parity) triangle, summed in
+        # f32 — exact (at most ``bl`` ones), and the MXU takes it.
+        c = jnp.where(cand, 1.0, 0.0).astype(jnp.bfloat16)
+        local = jnp.dot(c, tri_ref[:],
+                        preferred_element_type=jnp.float32
+                        ).astype(jnp.int32)
+        base = jnp.where(odd, occ_o[:, :1] + run_o[:, :1],
+                         occ_e[:, :1] + run_e[:, :1])
+        out_ref[:] = base + local
+        o, e = parity_counts(cand)
+        run_o[:] += o
+        run_e[:] += e
 
 
 def credit_rank(active: jnp.ndarray, cand: jnp.ndarray, *,
-                block_rows: int = 128, interpret=None) -> jnp.ndarray:
+                interpret=None) -> jnp.ndarray:
     """[..., L] int32 — Pallas twin of ``ref.credit_rank_ref``."""
     shape = active.shape
-    L = shape[-1]
-    rows = 1
-    for d in shape[:-1]:
-        rows *= d
-    act2 = active.reshape(rows, L)
-    cnd2 = cand.reshape(rows, L)
-    bn = min(block_rows, max(rows, 1))
-    act2, _ = _pad_rows(act2, bn)
-    cnd2, _ = _pad_rows(cnd2, bn)
+    rows, L = math.prod(shape[:-1]), shape[-1]
+    bn, rows_p = _row_block(rows)
+    bl, Lp = _lane_block(bn, L, cap=512)
+    nl = Lp // bl
+    act2 = _pad_to(_flat_rows(active), (rows_p, Lp))
+    cnd2 = _pad_to(_flat_rows(cand), (rows_p, Lp))
+    # blocks start at even lines, so in-block parity is line parity.
+    j = jnp.arange(bl)[:, None]                           # source line
+    i = jnp.arange(bl)[None, :]                           # ranked line
+    tri = ((j < i) & (((j ^ i) & 1) == 0)).astype(jnp.bfloat16)
     out = pl.pallas_call(
-        functools.partial(_credit_rank_kernel, L=L),
-        grid=(act2.shape[0] // bn,),
-        in_specs=[pl.BlockSpec((bn, L), lambda b: (b, 0)),
-                  pl.BlockSpec((bn, L), lambda b: (b, 0))],
-        out_specs=pl.BlockSpec((bn, L), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((act2.shape[0], L), jnp.int32),
+        _credit_rank_kernel,
+        grid=(rows_p // bn, 2, nl),
+        in_specs=[
+            pl.BlockSpec((bl, bl), lambda b, ph, l: (0, 0)),
+            # phase 0 walks ``active``; phase 1 parks it on its last block
+            # (no refetch) and walks ``cand`` instead.
+            pl.BlockSpec((bn, bl),
+                         lambda b, ph, l: (b, l * (1 - ph) + (nl - 1) * ph)),
+            pl.BlockSpec((bn, bl), lambda b, ph, l: (b, l * ph)),
+        ],
+        # the output block stays on line block 0 through phase 0 (never
+        # written there) and is first written back once phase 1 moves on.
+        out_specs=pl.BlockSpec((bn, bl), lambda b, ph, l: (b, l * ph)),
+        out_shape=jax.ShapeDtypeStruct((rows_p, Lp), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((bn, _LANE), jnp.int32)] * 4,
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
         interpret=_interpret() if interpret is None else interpret,
-    )(act2, cnd2)
-    return out[:rows].reshape(shape)
+    )(tri, act2, cnd2)
+    return out[:rows, :L].reshape(shape)
 
 
 # ---------------------------------------------------------------------------
@@ -114,8 +216,8 @@ def credit_rank(active: jnp.ndarray, cand: jnp.ndarray, *,
 
 
 def _arb_winner_kernel(ready_ref, rr_ref, out_ref, *, P: int):
-    ready = ready_ref[0]                                  # [P, L]
-    rr = rr_ref[:]                                        # [1, L] int32
+    ready = ready_ref[0]                                  # [P, bl]
+    rr = rr_ref[0]                                        # [1, bl] int32
     p = jax.lax.broadcasted_iota(jnp.int32, ready.shape, 0)
     prio = (p - rr) % P                                   # permutation/line
     score = jnp.where(ready, prio, P)
@@ -123,7 +225,7 @@ def _arb_winner_kernel(ready_ref, rr_ref, out_ref, *, P: int):
     # dominate; the only ties are at the fill score P, where min picks the
     # smallest p — jnp.argmin's first-minimum rule.
     enc = score * (P + 1) + p
-    out_ref[:] = (jnp.min(enc, axis=0, keepdims=True) % (P + 1)
+    out_ref[0] = (jnp.min(enc, axis=0, keepdims=True) % (P + 1)
                   ).astype(jnp.int32)
 
 
@@ -132,77 +234,83 @@ def arb_winner(ready_all: jnp.ndarray, arb_rr: jnp.ndarray, *,
     """[..., L] int32 — Pallas twin of ``ref.arb_winner_ref``.
 
     ``ready_all`` is ``[..., P, L]`` (P = R+1 participants), ``arb_rr``
-    ``[..., L]``; leading axes (the multi-home fold's H) become the grid.
+    ``[..., L]``; leading axes (the multi-home fold's H) and line blocks
+    form the grid.  The pointer rides as ``[n, 1, L]`` so its block's
+    last two dims are (full, lane-aligned) at every H.
     """
     P, L = ready_all.shape[-2:]
     lead = ready_all.shape[:-2]
-    n = 1
-    for d in lead:
-        n *= d
-    ready3 = ready_all.reshape(n, P, L)
-    rr2 = arb_rr.reshape(n, L).astype(jnp.int32)
+    n = math.prod(lead)
+    bl, Lp = _lane_block(P, L)
+    ready3 = _pad_to(ready_all.reshape(n, P, L), (n, P, Lp))
+    rr3 = _pad_to(arb_rr.reshape(n, 1, L).astype(jnp.int32), (n, 1, Lp))
     out = pl.pallas_call(
         functools.partial(_arb_winner_kernel, P=P),
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, P, L), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1, L), lambda b: (b, 0))],
-        out_specs=pl.BlockSpec((1, L), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, L), jnp.int32),
+        grid=(n, Lp // bl),
+        in_specs=[pl.BlockSpec((1, P, bl), lambda h, l: (h, 0, l)),
+                  pl.BlockSpec((1, 1, bl), lambda h, l: (h, 0, l))],
+        out_specs=pl.BlockSpec((1, 1, bl), lambda h, l: (h, 0, l)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, Lp), jnp.int32),
+        compiler_params=_params("parallel", "parallel"),
         interpret=_interpret() if interpret is None else interpret,
-    )(ready3, rr2)
-    return out.reshape(lead + (L,))
+    )(ready3, rr3)
+    return out[:, 0, :L].reshape(lead + (L,))
 
 
 # ---------------------------------------------------------------------------
 # count_fold
 # ---------------------------------------------------------------------------
 
+#: message-type rows of the count_fold accumulator; row 16 is the payload
+#: count.
+_N_TYPES = 16
 
-def _count_fold_kernel(msg_ref, mask_ref, pay_ref, cnt_ref, pay_out_ref):
-    @pl.when(pl.program_id(0) == 0)
+
+def _total(x: jnp.ndarray) -> jnp.ndarray:
+    """[bn, bl] int32 -> [1, 1] block total."""
+    return jnp.sum(_sum_lanes(x), axis=0, keepdims=True)
+
+
+def _count_fold_kernel(msg_ref, mask_ref, pay_ref, out_ref):
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
     def _init():
-        cnt_ref[:] = jnp.zeros_like(cnt_ref)
-        pay_out_ref[:] = jnp.zeros_like(pay_out_ref)
+        out_ref[:] = jnp.zeros_like(out_ref)
 
-    msg = msg_ref[:].reshape(-1, 1)                       # [bk, 1] int32
-    mask = mask_ref[:].reshape(-1, 1)                     # [bk, 1] bool
-    types = jax.lax.broadcasted_iota(jnp.int32, (msg.shape[0], 16), 1)
-    eq = (msg == types) & mask
-    cnt_ref[:] += eq.astype(jnp.int32).sum(0, keepdims=True)
-    pay_out_ref[:] += (mask_ref[:] & pay_ref[:]).astype(jnp.int32).sum(
-        keepdims=True)
+    msg = msg_ref[:].astype(jnp.int32)                    # [bn, bl]
+    mask = mask_ref[:]
+    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+    upd = jnp.where(row == _N_TYPES,
+                    _total(jnp.where(mask & pay_ref[:], 1, 0)), 0)
+    for k in range(_N_TYPES):   # static one-hot unroll, no reshape
+        upd = upd + jnp.where(
+            row == k, _total(jnp.where(mask & (msg == k), 1, 0)), 0)
+    out_ref[:] += upd
 
 
 def count_fold(mask: jnp.ndarray, msg: jnp.ndarray,
-               has_payload: jnp.ndarray, *, block: int = 2048,
+               has_payload: jnp.ndarray, *,
                interpret=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(delta [16] int32, payload delta [] int32) — Pallas twin of
-    ``ref.count_fold_ref``.  The grid walks flattened blocks sequentially,
-    accumulating into one resident output tile (masked padding adds 0)."""
-    flat_msg = msg.reshape(1, -1).astype(jnp.int32)
-    flat_mask = mask.reshape(1, -1)
-    flat_pay = has_payload.reshape(1, -1)
-    n = flat_msg.shape[1]
-    bk = min(block, max(n, 1))
-    pad = (-n) % bk
-    if pad:
-        width = [(0, 0), (0, pad)]
-        flat_msg = jnp.pad(flat_msg, width)
-        flat_mask = jnp.pad(flat_mask, width)
-        flat_pay = jnp.pad(flat_pay, width)
-    cnt, pay = pl.pallas_call(
+    ``ref.count_fold_ref``.  The grid walks ``[rows, L]`` blocks
+    sequentially, accumulating into one resident output tile (padding
+    lanes are masked off and add 0)."""
+    shape = (1,) + msg.shape if msg.ndim < 2 else msg.shape
+    rows, L = math.prod(shape[:-1]), shape[-1]
+    bn, rows_p = _row_block(rows)
+    bl, Lp = _lane_block(bn, L)
+    flat = [_pad_to(_flat_rows(x.reshape(shape)), (rows_p, Lp))
+            for x in (msg, mask, has_payload)]
+    spec = pl.BlockSpec((bn, bl), lambda b, l: (b, l))
+    out = pl.pallas_call(
         _count_fold_kernel,
-        grid=(flat_msg.shape[1] // bk,),
-        in_specs=[pl.BlockSpec((1, bk), lambda b: (0, b)),
-                  pl.BlockSpec((1, bk), lambda b: (0, b)),
-                  pl.BlockSpec((1, bk), lambda b: (0, b))],
-        out_specs=[pl.BlockSpec((1, 16), lambda b: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda b: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, 16), jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
+        grid=(rows_p // bn, Lp // bl),
+        in_specs=[spec] * 3,
+        out_specs=pl.BlockSpec((_N_TYPES + 1, _LANE), lambda b, l: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((_N_TYPES + 1, _LANE), jnp.int32),
+        compiler_params=_params("arbitrary", "arbitrary"),
         interpret=_interpret() if interpret is None else interpret,
-    )(flat_msg, flat_mask, flat_pay)
-    return cnt[0], pay[0, 0]
+    )(*flat)
+    return out[:_N_TYPES, 0], out[_N_TYPES, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -210,34 +318,43 @@ def count_fold(mask: jnp.ndarray, msg: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _lat_hist_kernel(lat_ref, ret_ref, out_ref, *, edges: Tuple[int, ...],
-                     nb: int):
-    lat = lat_ref[:]                                      # [br, L] int32
+def _lat_hist_kernel(lat_ref, ret_ref, out_ref, *, edges: Tuple[int, ...]):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    lat = lat_ref[:]                                      # [bn, bl] int32
     ret = ret_ref[:]
     bucket = jnp.zeros_like(lat)
     for e in edges:     # static unroll == searchsorted(side="right")
         bucket = bucket + (lat >= e).astype(jnp.int32)
-    cols = [((bucket == b) & ret).astype(jnp.int32).sum(-1, keepdims=True)
-            for b in range(nb)]
-    out_ref[:] = jnp.concatenate(cols, axis=-1)
+    col = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    upd = jnp.zeros(out_ref.shape, jnp.int32)
+    for b in range(out_ref.shape[1]):
+        upd = upd + jnp.where(
+            col == b, _sum_lanes(jnp.where(ret & (bucket == b), 1, 0)), 0)
+    out_ref[:] += upd
 
 
 def lat_hist(lat: jnp.ndarray, retired: jnp.ndarray,
-             edges: Tuple[int, ...], *, block_rows: int = 64,
-             interpret=None) -> jnp.ndarray:
-    """[R, NB] int32 — Pallas twin of ``ref.lat_hist_ref`` (2-D input)."""
+             edges: Tuple[int, ...], *, interpret=None) -> jnp.ndarray:
+    """[R, NB] int32 — Pallas twin of ``ref.lat_hist_ref`` (2-D input).
+    Each row block's histogram stays resident while the grid walks its
+    line blocks."""
     R, L = lat.shape
     nb = len(edges) + 1
-    br = min(block_rows, max(R, 1))
-    lat2, _ = _pad_rows(lat.astype(jnp.int32), br)
-    ret2, _ = _pad_rows(retired, br)
+    bn, rows_p = _row_block(R)
+    bl, Lp = _lane_block(bn, L)
+    lat2 = _pad_to(lat.astype(jnp.int32), (rows_p, Lp))
+    ret2 = _pad_to(retired, (rows_p, Lp))
+    spec = pl.BlockSpec((bn, bl), lambda b, l: (b, l))
     out = pl.pallas_call(
-        functools.partial(_lat_hist_kernel, edges=tuple(edges), nb=nb),
-        grid=(lat2.shape[0] // br,),
-        in_specs=[pl.BlockSpec((br, L), lambda b: (b, 0)),
-                  pl.BlockSpec((br, L), lambda b: (b, 0))],
-        out_specs=pl.BlockSpec((br, nb), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((lat2.shape[0], nb), jnp.int32),
+        functools.partial(_lat_hist_kernel, edges=tuple(edges)),
+        grid=(rows_p // bn, Lp // bl),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec((bn, nb), lambda b, l: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows_p, nb), jnp.int32),
+        compiler_params=_params("parallel", "arbitrary"),
         interpret=_interpret() if interpret is None else interpret,
     )(lat2, ret2)
     return out[:R]

@@ -270,7 +270,7 @@ def lat_hist_ref(lat: jnp.ndarray, retired: jnp.ndarray,
     ``sum_e (lat >= e)`` for sorted integer edges."""
     e = jnp.asarray(edges, jnp.int32)
     nb = len(edges) + 1
-    bucket = jnp.searchsorted(e, lat, side="right")
+    bucket = (lat[..., None] >= e).sum(-1)
     onehot = bucket[..., None] == jnp.arange(nb)
     return (onehot & retired[..., None]).sum(axis=1)
 

@@ -137,17 +137,16 @@ def moe_block_local(p: Params, cfg: ModelConfig, x: jnp.ndarray, mesh,
     Capacity semantics change slightly (per-shard capacity instead of
     global), which is standard for shard-local MoE (e.g. MaxText).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(p_, x_):
         y, aux = moe_block(p_, cfg, x_)
         return y, jax.lax.pmean(aux, dp_axes)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(), P(dp_axes, None, None)),
-                   out_specs=(P(dp_axes, None, None), P()),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(), P(dp_axes, None, None)),
+                       out_specs=(P(dp_axes, None, None), P()),
+                       check_vma=False)
     y, aux = fn(p, x)
     return y, aux
 

@@ -20,7 +20,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:
@@ -76,7 +75,7 @@ def pipeline_apply(mesh: Mesh, axis: str, layer_fn: Callable,
             jnp.where(sid == n_stages - 1, buf, jnp.zeros_like(buf)), axis)
         return buf
 
-    fn = shard_map(stage_fn, mesh=mesh,
-                   in_specs=(P(axis), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_fn, mesh=mesh,
+                       in_specs=(P(axis), P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_micro)

@@ -24,7 +24,7 @@ from .counters import (Counters, LAT_EDGES, RetirementTrace, SOJOURN_EDGES,
                        acc_total, assert_counts_match, hist_percentiles,
                        replay_reference, sojourn_summary, summarize,
                        validate_run)
-from .driver import StreamRun, default_steps, run_stream
+from .driver import StreamRun, default_steps, run_stream, stream_program
 from .fleet import fleet_steps, run_fleet
 from .observe import (ObserveConfig, ObsResult, OnlineViolation,
                       perfetto_events, write_perfetto)
@@ -38,6 +38,7 @@ __all__ = [
     "WorkloadSpec", "acc_total", "assert_counts_match", "check_schedule",
     "config_from_json", "config_to_json", "default_steps", "fleet_steps",
     "hist_percentiles", "perfetto_events", "replay_reference",
-    "run_fleet", "run_stream", "sojourn_summary", "summarize",
+    "run_fleet", "run_stream", "sojourn_summary", "stream_program",
+    "summarize",
     "validate_run", "write_perfetto",
 ]

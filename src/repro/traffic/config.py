@@ -262,13 +262,14 @@ class FleetConfig:
     members' ``driver.default_steps`` — every member retires within it.
 
     ``mesh_devices > 0`` runs the fleet data-parallel over that many
-    host devices (``shard_map`` over a 1-D "fleet" mesh): members are
-    independent, so per-member results stay bit-identical to the
-    single-device fleet — and to solo runs.  The member axis pads to a
-    device multiple by repeating members (their results are dropped on
-    readout, like PR 9's NOP remote columns).  Use
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to expose
-    N host-CPU devices (what CI's multi-device smoke job does).
+    devices (``shard_map`` over a 1-D "fleet" mesh) — the chips of a TPU
+    host, or on the CPU host devices exposed with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (what CI's
+    multi-device smoke job does).  Members are independent, so
+    per-member results stay bit-identical to the single-device fleet —
+    and to solo runs.  The member axis pads to a device multiple by
+    repeating members (their results are dropped on readout, like the
+    NOP remote columns).
     """
 
     members: Tuple[Tuple[EngineConfig, StreamConfig], ...] = ()

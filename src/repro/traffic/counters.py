@@ -135,8 +135,9 @@ def update_counters(ctr: Counters, st, *, retired: jnp.ndarray,
         hist = ctr.lat_hist + _kops.lat_hist(
             lat, retired, tuple(int(e) for e in LAT_EDGES))
     else:
-        bucket = jnp.searchsorted(jnp.asarray(LAT_EDGES), lat,
-                                  side="right")
+        # searchsorted(side="right") over sorted integer edges, as
+        # compares: no per-element gather on a TPU.
+        bucket = (lat[..., None] >= jnp.asarray(LAT_EDGES)).sum(-1)
         onehot = bucket[..., None] == jnp.arange(N_LAT_BUCKETS)
         hist = ctr.lat_hist + (onehot & retired[..., None]).sum(axis=1)
 

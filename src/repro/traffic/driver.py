@@ -138,6 +138,14 @@ class StreamRun(NamedTuple):
     #                           (> 0 = unserved queue growth: overload)
 
 
+def fleet_mesh(n_devices: int):
+    """The 1-D "fleet" mesh over the first ``n_devices`` devices — the
+    one mesh both the sharded fleet program and ``run_fleet``'s member
+    placement use."""
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices()[:n_devices]), ("fleet",))
+
+
 @functools.lru_cache(maxsize=None)
 def _jitted_stream(subset_name: str, collect_trace: bool, width: int,
                    hreq_shared: bool = False, n_homes: int = 1,
@@ -170,7 +178,7 @@ def _jitted_stream(subset_name: str, collect_trace: bool, width: int,
     program at the same step budget.
 
     ``mesh_devices > 0`` (fleet only) additionally shards the vmapped
-    member axis across that many host devices via ``shard_map`` over a
+    member axis across that many devices via ``shard_map`` over a
     1-D "fleet" mesh — members are data-parallel and fully independent,
     so each device runs the identical per-member program on its slice
     and results stay bit-identical to the single-device fleet (gated in
@@ -405,10 +413,8 @@ def _jitted_stream(subset_name: str, collect_trace: bool, width: int,
         vm = jax.vmap(run, in_axes=(0, 0, 0, 0, None, 0, 0, None, None,
                                     None, 0, 0, 0))
         if mesh_devices:
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import Mesh, PartitionSpec as P
-            mesh = Mesh(np.array(jax.devices()[:mesh_devices]),
-                        ("fleet",))
+            from jax.sharding import PartitionSpec as P
+            mesh = fleet_mesh(mesh_devices)
 
             def sharded(st, wl_op, wl_line, wl_value, tsteps, delays,
                         credits, width_cap, home_group, home_bw_t):
@@ -420,9 +426,9 @@ def _jitted_stream(subset_name: str, collect_trace: bool, width: int,
                           home_group, home_bw_t)
 
             fp = P("fleet")
-            fn = shard_map(sharded, mesh=mesh,
-                           in_specs=(fp,) * 4 + (P(),) + (fp,) * 5,
-                           out_specs=fp, check_rep=False)
+            fn = jax.shard_map(sharded, mesh=mesh,
+                               in_specs=(fp,) * 4 + (P(),) + (fp,) * 5,
+                               out_specs=fp, check_vma=False)
             return jax.jit(fn, donate_argnums=0)
         return jax.jit(vm, donate_argnums=0)
     return jax.jit(run, donate_argnums=0)
@@ -502,8 +508,19 @@ def run_stream(engine: EngineMN, wl, steps: int = 0,
         collect_trace=collect_trace), st)
 
 
-def _run_config(engine: EngineMN, cfg: StreamConfig,
-                st: Optional[EngineMNState]) -> StreamRun:
+class _Program(NamedTuple):
+    """A streaming run resolved up to the call: the validated workload,
+    arrival schedule and step budget, the jitted program, and every
+    operand after the engine state."""
+
+    wl: Workload
+    arr: Optional[ArrivalSchedule]
+    steps: int
+    fn: object
+    operands: tuple
+
+
+def _program(engine: EngineMN, cfg: StreamConfig) -> _Program:
     wl = cfg.workload
     if isinstance(wl, WorkloadSpec):
         wl = wl.materialize(engine.n_remotes, engine.n_lines)
@@ -534,9 +551,6 @@ def _run_config(engine: EngineMN, cfg: StreamConfig,
         last_arrival = int(np.asarray(arr.step).max()) if T else 0
     steps = cfg.steps or default_steps(T, engine.n_remotes, last_arrival)
 
-    st0 = engine.init() if st is None else st
-    base_msgs = np.asarray(st0.msg_count, np.int64)
-    base_payload = int(st0.payload_msgs)
     fn = _jitted_stream(engine.subset.name, cfg.collect_trace,
                         int(cfg.width), engine.shared_credits,
                         engine.n_homes, engine.home_bw, cfg.observe,
@@ -549,9 +563,33 @@ def _run_config(engine: EngineMN, cfg: StreamConfig,
     tf = None if cfg.type_filter is None else \
         jnp.asarray(cfg.type_filter, bool)
     arr_dev = None if arr is None else jnp.asarray(arr.step, jnp.int32)
-    carry, completed = fn(st0, wl.op, wl.line, wl.value,
-                          jnp.arange(steps, dtype=jnp.int32),
-                          engine.delays, engine.credits, lf, tf, arr_dev)
+    operands = (wl.op, wl.line, wl.value,
+                jnp.arange(steps, dtype=jnp.int32),
+                engine.delays, engine.credits, lf, tf, arr_dev)
+    return _Program(wl, arr, steps, fn, operands)
+
+
+def stream_program(engine: EngineMN, cfg: StreamConfig):
+    """``(program, operands)``: the jitted program ``run_stream(engine,
+    cfg)`` runs, with every operand (the engine state first) as a
+    ``jax.ShapeDtypeStruct``.  ``program.lower(*operands).compile()``
+    compiles it ahead of time — its text, cost and memory analysis —
+    without allocating the state."""
+    p = _program(engine, cfg)
+    shape = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a))
+    return p.fn, (jax.eval_shape(engine.init),) + tuple(
+        None if a is None else shape(a) for a in p.operands)
+
+
+def _run_config(engine: EngineMN, cfg: StreamConfig,
+                st: Optional[EngineMNState]) -> StreamRun:
+    p = _program(engine, cfg)
+    wl, arr, steps = p.wl, p.arr, p.steps
+    T = int(np.asarray(wl.op).shape[0])
+    st0 = engine.init() if st is None else st
+    base_msgs = np.asarray(st0.msg_count, np.int64)
+    base_payload = int(st0.payload_msgs)
+    carry, completed = p.fn(st0, *p.operands)
     trace = None
     if cfg.collect_trace:
         # compact O(T * R) record: the scratch row the non-retiring lanes
@@ -570,7 +608,7 @@ def _run_config(engine: EngineMN, cfg: StreamConfig,
                                compiled_specs(cfg.observe.specs))
     soj_hist = admit_hist = None
     backlog = 0
-    if open_loop:
+    if arr is not None:
         soj_hist = np.asarray(carry.soj.hist, np.int64)
         admit_hist = np.asarray(carry.soj.admit, np.int64)
         # backlog = arrived-but-never-issued ops when the budget ran out:

@@ -34,8 +34,10 @@ budget is the max of the members' ``default_steps``, and a solo run you
 compare against must use the same number (counter fields like ``steps``
 count the whole scan).
 
-``FleetConfig.mesh_devices > 0`` shards the member axis across host
-devices (``shard_map`` over a 1-D "fleet" mesh in the driver): members
+``FleetConfig.mesh_devices > 0`` shards the member axis across devices
+— chips on a TPU host, forced host devices on the CPU — (``shard_map``
+over a 1-D "fleet" mesh in the driver; each member's operands and state
+are placed straight onto its device, never stacked on one): members
 are independent, so each device runs the identical vmapped program on
 its slice and per-member results stay bit-identical to the
 single-device fleet.  Ragged member counts pad to a device multiple by
@@ -57,11 +59,19 @@ import numpy as np
 from ..core.engine_mn import make_engine_mn_state
 from .config import FleetConfig
 from .counters import RetirementTrace
-from .driver import StreamRun, _jitted_stream, default_steps
+from .driver import (StreamRun, _jitted_stream, default_steps,
+                     fleet_mesh)
 
 
-def _stack(trees):
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+def _member(x: jax.Array, i: int) -> jax.Array:
+    """Member ``i`` of a stacked leaf, sliced on the device that holds it:
+    indexing a "fleet"-sharded array would gather the slice onto one
+    device, and a full-size member's state is gigabytes."""
+    for shard in x.addressable_shards:
+        rows = range(x.shape[0])[shard.index[0]]
+        if i in rows:
+            return shard.data[i - rows.start]
+    raise IndexError(f"fleet member {i} is on no local device")
 
 
 def fleet_steps(fleet: FleetConfig) -> int:
@@ -92,11 +102,15 @@ def run_fleet(fleet: FleetConfig) -> List[StreamRun]:
     if mesh_n:
         avail = len(jax.devices())
         if mesh_n > avail:
+            platform = jax.devices()[0].platform
+            hint = (f"on CPU expose more with XLA_FLAGS="
+                    f"--xla_force_host_platform_device_count={mesh_n} "
+                    f"before importing jax" if platform == "cpu" else
+                    f"run on a {platform} host with at least {mesh_n} "
+                    f"chips")
             raise ValueError(
-                f"mesh_devices={mesh_n} but only {avail} device(s) are "
-                f"visible — on CPU expose more with "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{mesh_n} before importing jax")
+                f"mesh_devices={mesh_n} but only {avail} {platform} "
+                f"device(s) are visible — {hint}")
 
     # materialize + subset-check each member's workload at its own
     # [T, R_m], then pad to the fleet plane with NOP columns.
@@ -118,34 +132,46 @@ def run_fleet(fleet: FleetConfig) -> List[StreamRun]:
         out[:, :a.shape[1]] = a
         return out
 
-    wl_op = jnp.asarray(np.stack([pad_cols(w.op) for w in wls]))
-    wl_line = jnp.asarray(np.stack([pad_cols(w.line) for w in wls]))
-    wl_value = jnp.asarray(np.stack([pad_cols(w.value) for w in wls]))
-
-    # fresh R-max states (padded remotes start — and stay — idle), plus
-    # the per-member traced knobs.
-    st = _stack([make_engine_mn_state(
-        jnp.zeros((e.lines, e.block), jnp.float32), R_max,
-        packed=e0.packed)
-        for e, _ in members])
-    delays = jnp.stack([eng.delays for eng in engines])
-    credits = jnp.stack([eng.credits for eng in engines])
-    width_cap = jnp.asarray([s.width for _, s in members], jnp.int32)
-    home_group = jnp.asarray([e.homes for e, _ in members], jnp.int32)
-    home_bw_t = jnp.asarray([e.home_bw for e, _ in members], jnp.int32)
-
+    # under a mesh the member axis pads to a device multiple by repeating
+    # the last member; pad rows compute independently and are never read
+    # back.
     n_real = len(members)
+    rows = list(range(n_real))
     if mesh_n and n_real % mesh_n:
-        # pad the member axis to a device multiple by repeating the last
-        # member; pad rows compute independently and are never read back.
-        pad = mesh_n - n_real % mesh_n
-        rep = lambda a: jnp.concatenate(
-            [a, jnp.broadcast_to(a[-1:], (pad,) + a.shape[1:])])
-        st = jax.tree_util.tree_map(rep, st)
-        wl_op, wl_line, wl_value = rep(wl_op), rep(wl_line), rep(wl_value)
-        delays, credits = rep(delays), rep(credits)
-        width_cap, home_group = rep(width_cap), rep(home_group)
-        home_bw_t = rep(home_bw_t)
+        rows += [n_real - 1] * (mesh_n - n_real % mesh_n)
+
+    # every per-member operand is stacked on the host and placed straight
+    # onto its member's device ("fleet"-sharded under a mesh) — no stacked
+    # copy ever lands on one device first.
+    sharding = None
+    if mesh_n:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        sharding = NamedSharding(fleet_mesh(mesh_n), P("fleet"))
+
+    def stack(per_member):
+        return jax.device_put(
+            np.stack([np.asarray(per_member[i]) for i in rows]), sharding)
+
+    wl_op = stack([pad_cols(w.op) for w in wls])
+    wl_line = stack([pad_cols(w.line) for w in wls])
+    wl_value = stack([pad_cols(w.value) for w in wls])
+    delays = stack([eng.delays for eng in engines])
+    credits = stack([eng.credits for eng in engines])
+    width_cap = stack([np.int32(s.width) for _, s in members])
+    home_group = stack([np.int32(e.homes) for e, _ in members])
+    home_bw_t = stack([np.int32(e.home_bw) for e, _ in members])
+
+    # fresh R-max states (padded remotes start — and stay — idle), made
+    # on the device(s) that run them.
+    def fresh_states():
+        st1 = make_engine_mn_state(
+            jnp.zeros((e0.lines, e0.block), jnp.float32), R_max,
+            packed=e0.packed)
+        return jax.tree_util.tree_map(
+            lambda a: jnp.broadcast_to(a, (len(rows),) + a.shape), st1)
+
+    st = jax.jit(fresh_states, **(
+        {"out_shardings": sharding} if sharding else {}))()
 
     # the multi-home plane is EMULATED (home_group), so the program keys
     # on the flat layout; shared_credits/obs/open-loop are out of fleet
@@ -153,26 +179,27 @@ def run_fleet(fleet: FleetConfig) -> List[StreamRun]:
     fn = _jitted_stream(engines[0].subset.name, s0.collect_trace, W_max,
                         False, 1, 0, None, False, 0, 0,
                         engines[0].kernel_backend, True, mesh_n)
+    tsteps = jnp.arange(steps, dtype=jnp.int32)
     if mesh_n:
         # the sharded entry point takes no filter/arrival operands (they
         # are out of fleet scope and shard_map specs cover real args).
-        carry, completed = fn(st, wl_op, wl_line, wl_value,
-                              jnp.arange(steps, dtype=jnp.int32),
+        carry, completed = fn(st, wl_op, wl_line, wl_value, tsteps,
                               delays, credits,
                               width_cap, home_group, home_bw_t)
     else:
-        carry, completed = fn(st, wl_op, wl_line, wl_value,
-                              jnp.arange(steps, dtype=jnp.int32),
+        carry, completed = fn(st, wl_op, wl_line, wl_value, tsteps,
                               delays, credits, None, None, None,
                               width_cap, home_group, home_bw_t)
 
     completed = np.asarray(completed)
     retire = np.asarray(carry.retire) if s0.collect_trace else None
+    ctr_all = jax.device_get(carry.ctr)
+    msg_all = np.asarray(carry.st.msg_count, np.int64)
+    pay_all = np.asarray(carry.st.payload_msgs)
     runs = []
     for i, (eng, (e, s), wl) in enumerate(zip(engines, members, wls)):
         R_m = e.remotes
-        member = lambda x: x[i]
-        ctr = jax.device_get(jax.tree_util.tree_map(member, carry.ctr))
+        ctr = jax.tree_util.tree_map(lambda x: x[i], ctr_all)
         # the three per-remote counter planes carry padded rows (all
         # zero except lat_hist's never-touched rows) — slice them off so
         # the record is indistinguishable from the solo run's.
@@ -186,10 +213,10 @@ def run_fleet(fleet: FleetConfig) -> List[StreamRun]:
                 op=np.asarray(wl.op), line=np.asarray(wl.line),
                 value=np.asarray(wl.value), n_lines=e.lines)
         runs.append(StreamRun(
-            state=jax.tree_util.tree_map(member, carry.st),
+            state=jax.tree_util.tree_map(lambda x: _member(x, i), carry.st),
             counters=ctr,
-            msg_count=np.asarray(carry.st.msg_count[i], np.int64),
-            payload_msgs=int(carry.st.payload_msgs[i]),
+            msg_count=msg_all[i],
+            payload_msgs=int(pay_all[i]),
             trace=trace,
             completed=bool(completed[i]),
         ))

@@ -379,6 +379,8 @@ def main() -> None:
                          "JSON / Perfetto timelines / combined metrics "
                          "(the CI upload payload)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.core.engine_mn import MAX_REMOTES
     if not 1 <= args.remotes <= MAX_REMOTES:

@@ -296,3 +296,55 @@ def test_packed_pallas_backend_matches_packed_xla():
     for f, (x, y) in zip(a.counters._fields, zip(a.counters, b.counters)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
                                       err_msg=f)
+
+
+# ---------------------------------------------------------------------------
+# Gather-free table lookups and per-line remote selection: the engine's
+# TPU-friendly formulations agree with the gathers they replace.
+# ---------------------------------------------------------------------------
+
+import dataclasses  # noqa: E402
+
+from repro.core.protocol import (FULL_MOESI, bake, bake_mn,  # noqa: E402
+                                 lookup)
+
+TABLES = {f"{kind}.{f.name}": getattr(t, f.name)
+          for kind, t in (("base", bake(True)), ("mn", bake_mn(FULL_MOESI)))
+          for f in dataclasses.fields(t)
+          if isinstance(getattr(t, f.name), np.ndarray)}
+
+
+@pytest.mark.parametrize("name", sorted(TABLES))
+def test_lookup_matches_gather(name):
+    """Every index tuple of every protocol table, plus one out-of-range
+    index on each side per axis: ``lookup`` returns what the gather
+    ``jnp.asarray(table)[idx]`` returns, dtype included."""
+    t = TABLES[name]
+    axes = [np.arange(-1, d + 1) for d in t.shape]
+    idx = [jnp.asarray(a.ravel(), jnp.int32)
+           for a in np.meshgrid(*axes, indexing="ij")]
+    want = jnp.asarray(t)[tuple(jnp.clip(jnp.where(i < 0, i + d, i), 0,
+                                         d - 1)
+                                for i, d in zip(idx, t.shape))]
+    got = lookup(t, *idx)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("shape", [(5, 16), (2, 3, 8), (4, 8, 3)])
+def test_take_remote_matches_take_along_axis(shape):
+    """``_take_remote`` picks row ``node[l]`` of each line, for [R, L],
+    home-batched [H, R, L] and payload [R, L, B] planes."""
+    rng = np.random.default_rng(SEED)
+    arr = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    payload = shape == (4, 8, 3)
+    R = shape[0] if payload else shape[-2]
+    node_shape = shape[-2:-1] if payload else shape[:-2] + shape[-1:]
+    node = jnp.asarray(rng.integers(0, R, node_shape).astype(np.int32))
+    if payload:
+        want = arr[node, jnp.arange(shape[1])]
+    else:
+        want = jnp.take_along_axis(arr, node[..., None, :],
+                                   axis=-2)[..., 0, :]
+    np.testing.assert_array_equal(np.asarray(dmn._take_remote(arr, node)),
+                                  np.asarray(want))

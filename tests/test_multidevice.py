@@ -73,7 +73,6 @@ def test_pushdown_lookup_8shards():
 
 def test_compressed_psum_matches_exact():
     r = run_sub("""
-        from jax.experimental.shard_map import shard_map
         from repro.optim import compression
         mesh = Mesh(np.array(jax.devices()).reshape(8), ("pod",))
         g = jax.random.normal(jax.random.key(1), (8, 64)) * 0.1
@@ -81,8 +80,8 @@ def test_compressed_psum_matches_exact():
         def f(gl, el):
             mean, e2 = compression.compressed_psum(gl[0], el[0], "pod")
             return mean, e2[None]
-        fn = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                       out_specs=(P(), P("pod")), check_rep=False)
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                           out_specs=(P(), P("pod")), check_vma=False)
         err = jnp.zeros((8, 64))
         mean, err = fn(g, err)
         exact = g.mean(axis=0)
@@ -140,7 +139,8 @@ def test_multipod_train_step_2x2x2():
 
 def test_sharded_fleet_bit_identical_to_solo():
     """``FleetConfig.mesh_devices`` shards the member axis over host
-    devices; every member's counters/msg_count must equal BOTH the
+    devices, one member's state per device; every member's
+    counters/msg_count must equal BOTH the
     single-device fleet's and the solo ``run_stream`` run's, including a
     ragged member count that pads by repeating the last member."""
     r = run_sub("""
@@ -177,9 +177,15 @@ def test_sharded_fleet_bit_identical_to_solo():
                    == np.asarray(b.msg_count)).all().item()
         result["ok"] = bool(ok)
         result["n"] = len(shard)
+        # each member's state stays on the device that ran it — never
+        # gathered onto one device.
+        result["homes"] = [
+            sorted({d.id for leaf in jax.tree_util.tree_leaves(b.state)
+                    for d in leaf.devices()}) for b in shard]
     """)
     assert r["ok"], r
     assert r["n"] == 4
+    assert r["homes"] == [[0], [1], [2], [3]]
 
 
 def test_multipod_decode_2x2x2():
