@@ -39,20 +39,6 @@ Gate rules (exit 1 on violation):
 
 ``--write-baseline`` refreshes the committed baseline file instead of
 comparing (run it locally when a PR intentionally shifts throughput).
-
-``--wallclock`` additionally runs the WALL-CLOCK timing harness (zipfian
-R=64, issue widths 1 and 4): warmup-disciplined (one compile+warm pass,
-then best-of-N), reporting steps/s and sustained ops/s.  It also times
-the bit-packed directory planes against the dense W=4 acceptance stream
-(``packed_w4``: sustained ops/s, speedup vs dense, and the
-DETERMINISTIC directory-state footprint ratio, gated >= 4 at R=64) and
-the shard_map'd R x W grid fleet against the single-device fleet
-(``sharded_grid``: speedup gated >= 1 when >= 2 devices are visible —
-sharding independent members must never lose wall time).  Raw
-wall-clock numbers are hardware-dependent and therefore NEVER gated —
-they ride along in the JSON record for the cross-PR trajectory
-(``collect_history.py``'s ``packed_speedup_x`` / ``shard_speedup_x``
-columns).
 """
 from __future__ import annotations
 
@@ -98,31 +84,6 @@ FLEET_GRID = tuple((r, w) for r in (4, 8, 16, 32) for w in (1, 2, 4))
 FLEET_HOMES = (1, 2, 4)
 FLEET_HOMES_REMOTES = 8
 FLEET_HOME_BW = 1
-
-#: the wall-clock harness config: THE acceptance stream of the hot-path
-#: overhaul (zipfian, R=64), timed at issue widths 1 and 4.
-WALLCLOCK_CONFIG = dict(n_remotes=64, n_lines=32, block=4, ops=48)
-WALLCLOCK_WIDTHS = (1, 4)
-
-#: bit-packed directory planes (docs/perf.md): the SAME acceptance
-#: stream with ``EngineConfig(packed=True)`` — [R, L] int8 presence /
-#: pending planes become [2, L, ceil(R/32)] uint32 bitmask words.  The
-#: wall-clock delta is hardware-dependent (recorded, never gated); the
-#: directory-state footprint ratio is DETERMINISTIC (2*R*L bytes dense
-#: vs 16*L*W packed = R/(8W)) and gated >= 4 at the R=64 acceptance
-#: shape whenever the --wallclock record is present.
-PACKED_WALLCLOCK_WIDTH = 4
-PACKED_STATE_RATIO_FLOOR = 4.0
-
-#: sharded-fleet wall clock: the R x W grid fleet run single-device vs
-#: shard_map over the "fleet" mesh axis (FleetConfig.mesh_devices).
-#: Requires >= 2 visible devices (CI forces 4 host devices with
-#: XLA_FLAGS=--xla_force_host_platform_device_count=4); with a single
-#: device the record is marked skipped.  Speedup >= SHARD_SPEEDUP_FLOOR
-#: is a sanity gate: sharding independent members must never LOSE wall
-#: time beyond noise.
-SHARD_MESH_DEVICES = 4
-SHARD_SPEEDUP_FLOOR = 1.0
 
 #: observability-overhead harness: the acceptance config (zipfian R=64)
 #: at H in {1, 2}, traced (EWF ring + online NFA specs + phase
@@ -382,182 +343,6 @@ def run_fleet_bench() -> dict:
     }
 
 
-def run_wallclock(repeats: int = 3) -> dict:
-    """Warmup-disciplined wall-clock timing of the acceptance stream.
-
-    Separate from the deterministic simulation metrics above: wall-clock
-    moves with runner hardware, so it is reported (for the trajectory) but
-    NEVER gated.  Discipline: the first call pays compile + cache warmup;
-    the reported numbers are best-of-``repeats`` on the warmed program.
-    ``sustained_ops_per_s`` divides retired ops by the wall-time of the
-    ACTIVE steps only (the generous drain-tail budget must not dilute the
-    rate) — the metric of the >=1.5x acceptance criterion.
-    """
-    from repro.traffic import (EngineConfig, StreamConfig, WorkloadSpec,
-                               default_steps, run_stream, summarize)
-
-    cfg = WALLCLOCK_CONFIG
-    n_remotes, n_lines = cfg["n_remotes"], cfg["n_lines"]
-    steps = default_steps(cfg["ops"], n_remotes)
-    ecfg = EngineConfig(remotes=n_remotes, lines=n_lines,
-                        block=cfg["block"])
-    out = {}
-    for width in WALLCLOCK_WIDTHS:
-        eng = ecfg.build()
-        scfg = StreamConfig(workload=WorkloadSpec("zipfian",
-                                                  ops=cfg["ops"], seed=0),
-                            steps=steps, width=width)
-        t0 = time.perf_counter()
-        run = run_stream(eng, scfg)                         # compile+warm
-        t_compile = time.perf_counter() - t0
-        best = float("inf")
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            run = run_stream(eng, scfg)
-            best = min(best, time.perf_counter() - t0)
-        assert run.completed, "wallclock stream did not drain"
-        s = summarize(run.counters, run.msg_count)
-        steps_per_s = steps / best
-        out[f"w{width}"] = {
-            "config": dict(cfg, width=width, steps=steps),
-            "completed": True,
-            "wall_s": round(best, 3),
-            "compile_s": round(t_compile, 3),
-            "steps_per_s": round(steps_per_s, 1),
-            "ops_per_step": round(float(s["ops_per_step"]), 4),
-            "active_steps": int(s["active_steps"]),
-            "mean_mshr_occupancy": round(
-                float(s["mean_mshr_occupancy"]), 2),
-            "sustained_ops_per_s": round(
-                float(s["ops_per_step"]) * steps_per_s, 1),
-        }
-    out["packed_w%d" % PACKED_WALLCLOCK_WIDTH] = _wallclock_packed(
-        out[f"w{PACKED_WALLCLOCK_WIDTH}"], repeats)
-    return out
-
-
-def _wallclock_packed(dense_rec: dict, repeats: int) -> dict:
-    """Packed-vs-dense wall clock on the acceptance stream (same shape,
-    seed and step budget as the dense ``w4`` record), plus the
-    deterministic directory-state footprint ratio the packing buys.
-
-    The packed engine runs the SAME schedule bit-identically (the packed
-    bisimulation tier in ``tests/test_coherency_kernels.py`` gates
-    that); here only the wall-clock and footprint move.  On CPU the
-    word ops trade [R, L] boolean lanes for [W] uint32 words per line
-    (R/W = 32x fewer lanes at R=64) but pay pack/unpack shuffles at the
-    dense transport boundary, so the measured speedup is informational;
-    the footprint ratio (2*R*L dense bytes vs 16*L*W packed) is exact
-    and gated at ``PACKED_STATE_RATIO_FLOOR``."""
-    from repro.traffic import (EngineConfig, StreamConfig, WorkloadSpec,
-                               default_steps, run_stream, summarize)
-
-    cfg = WALLCLOCK_CONFIG
-    n_remotes, n_lines = cfg["n_remotes"], cfg["n_lines"]
-    width = PACKED_WALLCLOCK_WIDTH
-    steps = default_steps(cfg["ops"], n_remotes)
-    eng = EngineConfig(remotes=n_remotes, lines=n_lines,
-                       block=cfg["block"], packed=True).build()
-    scfg = StreamConfig(workload=WorkloadSpec("zipfian", ops=cfg["ops"],
-                                              seed=0),
-                        steps=steps, width=width)
-    t0 = time.perf_counter()
-    run = run_stream(eng, scfg)                             # compile+warm
-    t_compile = time.perf_counter() - t0
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        run = run_stream(eng, scfg)
-        best = min(best, time.perf_counter() - t0)
-    assert run.completed, "packed wallclock stream did not drain"
-    s = summarize(run.counters, run.msg_count)
-    W = (n_remotes + 31) // 32
-    steps_per_s = steps / best
-    return {
-        "config": dict(cfg, width=width, steps=steps, packed=True),
-        "completed": True,
-        "wall_s": round(best, 3),
-        "compile_s": round(t_compile, 3),
-        "steps_per_s": round(steps_per_s, 1),
-        "ops_per_step": round(float(s["ops_per_step"]), 4),
-        "sustained_ops_per_s": round(
-            float(s["ops_per_step"]) * steps_per_s, 1),
-        # hardware-dependent: dense w4 wall / packed wall
-        "speedup_x_vs_dense": round(dense_rec["wall_s"] / best, 3),
-        # deterministic: directory-state bytes, dense / packed
-        "state_bytes_ratio": round(2 * n_remotes * n_lines
-                                   / (16.0 * n_lines * W), 2),
-        "lane_ratio": n_remotes // W,
-        "state_ratio_floor": PACKED_STATE_RATIO_FLOOR,
-    }
-
-
-def run_wallclock_sharded(repeats: int = 3) -> dict:
-    """Sharded-vs-solo wall clock of the R x W grid fleet.
-
-    The same ``FLEET_GRID`` members run as one vmapped program on a
-    single device, then shard_map'd across ``SHARD_MESH_DEVICES`` host
-    devices (``FleetConfig.mesh_devices``).  Member results are
-    bit-identical either way (gated in ``tests/test_multidevice.py``
-    and by the fleet section above); this record times the execution
-    strategies against each other.  With fewer than 2 visible devices
-    the record is marked skipped — CI forces 4 host devices via
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``."""
-    import jax
-    import numpy as np
-    from repro.traffic import (EngineConfig, FleetConfig, StreamConfig,
-                               WorkloadSpec, run_fleet)
-
-    avail = len(jax.devices())
-    mesh_n = min(SHARD_MESH_DEVICES, avail)
-    if mesh_n < 2:
-        return {"skipped": f"only {avail} visible device(s); set "
-                           f"XLA_FLAGS=--xla_force_host_platform_"
-                           f"device_count={SHARD_MESH_DEVICES}"}
-    cores = os.cpu_count() or 1
-    if cores < 2:
-        # forced host devices on a single core time-slice one CPU — the
-        # speedup gate would measure scheduler noise, not sharding.
-        return {"skipped": f"{cores} CPU core(s): forced host devices "
-                           f"cannot run in parallel"}
-    members = tuple(
-        (EngineConfig(remotes=r, lines=FLEET_CONFIG["n_lines"]),
-         StreamConfig(workload=WorkloadSpec(
-             "zipfian", ops=FLEET_CONFIG["ops"], seed=0), width=w))
-        for r, w in FLEET_GRID)
-
-    def _best(mesh):
-        fleet = FleetConfig(members=members, mesh_devices=mesh)
-        t0 = time.perf_counter()
-        runs = run_fleet(fleet)                             # compile+warm
-        compile_s = time.perf_counter() - t0
-        best = float("inf")
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            runs = run_fleet(fleet)
-            best = min(best, time.perf_counter() - t0)
-        return runs, best, compile_s
-
-    solo_runs, solo_best, solo_compile = _best(0)
-    shard_runs, shard_best, shard_compile = _best(mesh_n)
-    identical = all(
-        np.array_equal(np.asarray(a.counters.retired),
-                       np.asarray(b.counters.retired))
-        and np.array_equal(np.asarray(a.msg_count), np.asarray(b.msg_count))
-        for a, b in zip(solo_runs, shard_runs))
-    return {
-        "members": len(members),
-        "mesh_devices": mesh_n,
-        "solo_wall_s": round(solo_best, 3),
-        "sharded_wall_s": round(shard_best, 3),
-        "solo_compile_s": round(solo_compile, 3),
-        "sharded_compile_s": round(shard_compile, 3),
-        "speedup_x": round(solo_best / shard_best, 3),
-        "speedup_floor": SHARD_SPEEDUP_FLOOR,
-        "bit_identical_to_solo": bool(identical),
-    }
-
-
 def run_observability(repeats: int = 5) -> dict:
     """Traced-vs-untraced overhead on the acceptance stream (R=64).
 
@@ -692,7 +477,7 @@ def run_knee() -> dict:
     return out
 
 
-def collect(wallclock: bool = False) -> dict:
+def collect() -> dict:
     import jax
     rec = {
         "schema": 4,
@@ -705,9 +490,6 @@ def collect(wallclock: bool = False) -> dict:
         "observability": run_observability(),
         "knee": run_knee(),
     }
-    if wallclock:
-        rec["wallclock"] = run_wallclock()
-        rec["wallclock"]["sharded_grid"] = run_wallclock_sharded()
     return rec
 
 
@@ -793,32 +575,6 @@ def gate(current: dict, baseline: dict, tolerance: float) -> list:
                 f"{rec['overhead_limit']:.2f} (traced "
                 f"{rec['traced_steps_per_s']:.0f} vs untraced "
                 f"{rec['untraced_steps_per_s']:.0f} steps/s)")
-    # wallclock sanity gates (only when the --wallclock record rode
-    # along): the packed directory-state footprint ratio is
-    # deterministic and must clear its floor, and sharding independent
-    # fleet members across devices must never lose wall time (speedup
-    # >= 1) — raw wall times themselves stay un-gated.
-    wc = current.get("wallclock", {})
-    pk = wc.get("packed_w%d" % PACKED_WALLCLOCK_WIDTH)
-    if pk is not None:
-        if not pk["completed"]:
-            bad.append("wallclock packed: stream did not complete")
-        if pk["state_bytes_ratio"] < pk["state_ratio_floor"]:
-            bad.append(
-                f"wallclock packed: directory-state bytes ratio "
-                f"{pk['state_bytes_ratio']} below floor "
-                f"{pk['state_ratio_floor']}")
-    sh = wc.get("sharded_grid")
-    if sh is not None and "skipped" not in sh:
-        if not sh["bit_identical_to_solo"]:
-            bad.append("wallclock sharded_grid: sharded fleet diverged "
-                       "from single-device fleet")
-        if sh["speedup_x"] < sh["speedup_floor"]:
-            bad.append(
-                f"wallclock sharded_grid: speedup {sh['speedup_x']}x "
-                f"below sanity floor {sh['speedup_floor']}x (solo "
-                f"{sh['solo_wall_s']}s vs sharded "
-                f"{sh['sharded_wall_s']}s)")
     # knee gate: the open-loop service model must keep its shape — the
     # past-saturation point detects overload (unserved backlog in a
     # fixed window), the sub-saturation points complete with p99 sojourn
@@ -857,14 +613,11 @@ def main() -> None:
                     help="max allowed ops/step regression (fraction)")
     ap.add_argument("--write-baseline", action="store_true",
                     help="refresh the baseline file instead of gating")
-    ap.add_argument("--wallclock", action="store_true",
-                    help="also run the wall-clock timing harness (zipfian "
-                         "R=64, W in {1,4}; reported, never gated)")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
 
-    current = collect(wallclock=args.wallclock)
+    current = collect()
     with open(args.out, "w") as f:
         json.dump(current, f, indent=1, sort_keys=True)
         f.write("\n")
@@ -872,11 +625,10 @@ def main() -> None:
 
     if args.write_baseline:
         # the committed baseline carries ONLY deterministic metrics —
-        # wall-clock (and the observability overhead ratio, which is a
-        # wall-clock ratio gated by an absolute limit instead) moves with
-        # the machine that happened to refresh it.
-        base = {k: v for k, v in current.items()
-                if k not in ("wallclock", "observability")}
+        # the observability overhead ratio is a wall-clock ratio gated by
+        # an absolute limit instead, and moves with the machine that
+        # happened to refresh it.
+        base = {k: v for k, v in current.items() if k != "observability"}
         with open(args.baseline, "w") as f:
             json.dump(base, f, indent=1, sort_keys=True)
             f.write("\n")
@@ -913,24 +665,6 @@ def main() -> None:
               f"{c['per_point_total_s']}s vs fleet {c['fleet_s']}s "
               f"({c['amortization_x']}x amortization; homes fleet "
               f"{c['homes_fleet_s']}s)")
-    for key, rec in sorted(current.get("wallclock", {}).items()):
-        if key == "sharded_grid":
-            if "skipped" in rec:
-                print(f"wallclock sharded_grid: skipped ({rec['skipped']})")
-            else:
-                print(f"wallclock sharded_grid: {rec['members']} members "
-                      f"on {rec['mesh_devices']} devices, solo "
-                      f"{rec['solo_wall_s']}s vs sharded "
-                      f"{rec['sharded_wall_s']}s ({rec['speedup_x']}x) "
-                      f"bit_identical {rec['bit_identical_to_solo']}")
-            continue
-        extra = ""
-        if "speedup_x_vs_dense" in rec:
-            extra = (f" packed {rec['speedup_x_vs_dense']}x vs dense, "
-                     f"state bytes {rec['state_bytes_ratio']}x")
-        print(f"wallclock {key}: {rec['steps_per_s']} steps/s "
-              f"sustained {rec['sustained_ops_per_s']} ops/s "
-              f"compile {rec['compile_s']}s" + extra)
     for key, rec in sorted(current.get("observability", {}).items()):
         print(f"observability {key}: overhead "
               f"{rec['overhead_ratio']:.3f}x (limit "

@@ -10,10 +10,8 @@ per run) and merge:
 
 Records are sorted by their ``generated_unix`` stamp; one row per record,
 one column per streaming config's deterministic ops/step (the gated
-metric), with max_wait, wall-clock, per-config compile time, the
-fleet compile-amortization factor and the packed-plane / sharded-fleet
-wall speedups (``packed_speedup_x`` / ``shard_speedup_x``, from
-``--wallclock`` records) riding along.  Missing configs (older
+metric), with max_wait, wall-clock, per-config compile time and the
+fleet compile-amortization factor riding along.  Missing configs (older
 records predate r32/W=2, schema<3 records predate the fleet section)
 render as ``-`` — the table is the union, so the trajectory stays
 readable across config-set changes.
@@ -65,31 +63,13 @@ def _fleet_amort(rec: dict):
     return rec.get("fleet", {}).get("compile", {}).get("amortization_x")
 
 
-def _packed_speedup(rec: dict):
-    """Packed-vs-dense wall speedup from the --wallclock record (schema
-    >= 4); None for records without the wallclock section."""
-    for key, wc in rec.get("wallclock", {}).items():
-        if key.startswith("packed_") and isinstance(wc, dict):
-            return wc.get("speedup_x_vs_dense")
-    return None
-
-
-def _shard_speedup(rec: dict):
-    """Sharded-vs-solo fleet wall speedup; None when absent or when the
-    record ran on a single device (marked skipped)."""
-    sh = rec.get("wallclock", {}).get("sharded_grid")
-    if isinstance(sh, dict) and "skipped" not in sh:
-        return sh.get("speedup_x")
-    return None
-
-
 def to_markdown(recs: List[dict]) -> str:
     keys = config_keys(recs)
     head = (["date (UTC)", "jax"]
             + [f"{k} ops/step" for k in keys]
             + [f"{k} max_wait" for k in keys]
             + [f"{k} compile_s" for k in keys]
-            + ["fleet amort x", "packed x", "shard x"])
+            + ["fleet amort x"])
     lines = ["| " + " | ".join(head) + " |",
              "|" + "---|" * len(head)]
     for rec in recs:
@@ -100,9 +80,8 @@ def to_markdown(recs: List[dict]) -> str:
                 cfg = rec["streaming"].get(k)
                 row.append(fmt.format(cfg[field]) if cfg and field in cfg
                            else "-")
-        for v in (_fleet_amort(rec), _packed_speedup(rec),
-                  _shard_speedup(rec)):
-            row.append("-" if v is None else f"{v}")
+        v = _fleet_amort(rec)
+        row.append("-" if v is None else f"{v}")
         lines.append("| " + " | ".join(row) + " |")
     return "\n".join(lines) + "\n"
 
@@ -114,8 +93,7 @@ def to_csv(recs: List[dict]) -> str:
             + [f"{k}_max_wait" for k in keys]
             + [f"{k}_wall_s" for k in keys]
             + [f"{k}_compile_s" for k in keys]
-            + ["fleet_amortization_x", "packed_speedup_x",
-               "shard_speedup_x"])
+            + ["fleet_amortization_x"])
     rows = [",".join(head)]
     for rec in recs:
         row = [str(rec.get("generated_unix", "")),
@@ -124,9 +102,8 @@ def to_csv(recs: List[dict]) -> str:
             for k in keys:
                 cfg = rec["streaming"].get(k)
                 row.append(str(cfg[field]) if cfg and field in cfg else "")
-        for v in (_fleet_amort(rec), _packed_speedup(rec),
-                  _shard_speedup(rec)):
-            row.append("" if v is None else str(v))
+        v = _fleet_amort(rec)
+        row.append("" if v is None else str(v))
         rows.append(",".join(row))
     return "\n".join(rows) + "\n"
 

@@ -202,15 +202,16 @@ def _count(msg_count, payload_msgs, mask, msg, has_payload,
     compare vectorizes and counts identically.  ``backend="pallas"``
     routes the fold through the ``kernels.coherency_step.count_fold``
     kernel (bit-identical integer arithmetic)."""
-    if backend == "pallas":
-        from ..kernels import ops as _kops
-        delta, pay = _kops.count_fold(mask, msg, has_payload)
-        return msg_count + delta, payload_msgs + pay
-    eq = msg.astype(jnp.int32)[..., None] == jnp.arange(16)
-    axes = tuple(range(eq.ndim - 1))
-    msg_count = msg_count + (eq & mask[..., None]).sum(axes)
-    payload_msgs = payload_msgs + (mask & has_payload).sum()
-    return msg_count, payload_msgs
+    with jax.named_scope("eci.counters"):
+        if backend == "pallas":
+            from ..kernels import ops as _kops
+            delta, pay = _kops.count_fold(mask, msg, has_payload)
+            return msg_count + delta, payload_msgs + pay
+        eq = msg.astype(jnp.int32)[..., None] == jnp.arange(16)
+        axes = tuple(range(eq.ndim - 1))
+        msg_count = msg_count + (eq & mask[..., None]).sum(axes)
+        payload_msgs = payload_msgs + (mask & has_payload).sum()
+        return msg_count, payload_msgs
 
 
 def stall_unready_ops(tables: DenseTables, ch_req, eff_op: jnp.ndarray,

@@ -305,13 +305,15 @@ def _ready(ch: tp.Channel, delay_l: jnp.ndarray) -> jnp.ndarray:
     arbitration (step 4) must pop only the WINNING slot per line — every
     other channel uses the batched ``deliver`` directly.  ``delay_l`` is
     the caller's hoisted per-line delay gather for the channel's class."""
-    return (ch.msg != int(MsgType.NOP)) & (ch.age >= delay_l[None, :])
+    with jax.named_scope("eci.transport"):
+        return (ch.msg != int(MsgType.NOP)) & (ch.age >= delay_l[None, :])
 
 
 def _pop(ch: tp.Channel, mask: jnp.ndarray) -> tp.Channel:
     """Free the slots in ``mask``; fields are read from the input channel."""
-    return ch._replace(msg=jnp.where(mask, jnp.int8(int(MsgType.NOP)),
-                                     ch.msg))
+    with jax.named_scope("eci.transport"):
+        return ch._replace(msg=jnp.where(mask, jnp.int8(int(MsgType.NOP)),
+                                         ch.msg))
 
 
 def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
@@ -413,16 +415,18 @@ def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
     # (``home_group = 1`` degenerates to global parity, bit-identical).
     par = (lines & 1) if home_group is None \
         else ((lines // home_group) & 1)
-    dly_req = tp.vc_value(delays, par, tp.CLASS_REMOTE_REQ)
-    dly_resp = tp.vc_value(delays, par, tp.CLASS_HOME_RESP)
-    dly_hreq = tp.vc_value(delays, par, tp.CLASS_HOME_REQ)
-    dly_hresp = tp.vc_value(delays, par, tp.CLASS_REMOTE_RESP)
+    with jax.named_scope("eci.transport"):
+        dly_req = tp.vc_value(delays, par, tp.CLASS_REMOTE_REQ)
+        dly_resp = tp.vc_value(delays, par, tp.CLASS_HOME_RESP)
+        dly_hreq = tp.vc_value(delays, par, tp.CLASS_HOME_REQ)
+        dly_hresp = tp.vc_value(delays, par, tp.CLASS_REMOTE_RESP)
 
     # accumulate new home-side wants.
-    want_read = st.want_read | want_read
-    want_write = st.want_write | want_write
-    wv = jnp.where((want_write & ~st.want_write)[..., None], wval,
-                   st.want_wval)
+    with jax.named_scope("eci.directory"):
+        want_read = st.want_read | want_read
+        want_write = st.want_write | want_write
+        wv = jnp.where((want_write & ~st.want_write)[..., None], wval,
+                       st.want_wval)
 
     # ---- 1. time advances on all channels --------------------------------
     ch_req, ch_resp = tp.tick(st.ch_req), tp.tick(st.ch_resp)
@@ -432,38 +436,43 @@ def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
     ch_hresp_in = ch_hresp
     ch_hresp, hr_arr = tp.deliver(ch_hresp, tp.CLASS_REMOTE_RESP, delays,
                                   delay_l=dly_hresp)
-    if packed:
-        # plane 0 of the packed MSHR mask is "HOME_DOWNGRADE_S pending";
-        # absorb reads rep_kind only under hr_arr, and a reply can only
-        # arrive for a sent (= pending) downgrade, so the bit IS the kind.
-        rep_kind = jnp.where(
-            dmn.unpack_mask(st.hreq_pending[..., 0, :, :], R),
-            jnp.int8(int(MnAbsorb.REPLY_S)), jnp.int8(int(MnAbsorb.REPLY_I)))
-    else:
-        rep_kind = jnp.where(
-            st.hreq_pending == int(MsgType.HOME_DOWNGRADE_S),
-            jnp.int8(int(MnAbsorb.REPLY_S)), jnp.int8(int(MnAbsorb.REPLY_I)))
-    dstate = dmn.absorb(tables_mn, st.dir, hr_arr, rep_kind,
-                        ch_hresp_in.dirty, ch_hresp_in.payload,
-                        backend=kernel_backend)
-    if packed:
-        hreq_pending = st.hreq_pending & \
-            ~dmn.pack_mask(hr_arr)[..., None, :, :]
-    else:
-        hreq_pending = jnp.where(hr_arr, nop, st.hreq_pending)
+    with jax.named_scope("eci.directory"):
+        if packed:
+            # plane 0 of the packed MSHR mask is "HOME_DOWNGRADE_S
+            # pending"; absorb reads rep_kind only under hr_arr, and a reply
+            # can only arrive for a sent (= pending) downgrade, so the bit
+            # IS the kind.
+            rep_kind = jnp.where(
+                dmn.unpack_mask(st.hreq_pending[..., 0, :, :], R),
+                jnp.int8(int(MnAbsorb.REPLY_S)),
+                jnp.int8(int(MnAbsorb.REPLY_I)))
+        else:
+            rep_kind = jnp.where(
+                st.hreq_pending == int(MsgType.HOME_DOWNGRADE_S),
+                jnp.int8(int(MnAbsorb.REPLY_S)),
+                jnp.int8(int(MnAbsorb.REPLY_I)))
+        dstate = dmn.absorb(tables_mn, st.dir, hr_arr, rep_kind,
+                            ch_hresp_in.dirty, ch_hresp_in.payload,
+                            backend=kernel_backend)
+        if packed:
+            hreq_pending = st.hreq_pending & \
+                ~dmn.pack_mask(hr_arr)[..., None, :, :]
+        else:
+            hreq_pending = jnp.where(hr_arr, nop, st.hreq_pending)
     msg_count, payload_msgs = _count(msg_count, payload_msgs, hr_arr,
                                      ch_hresp_in.msg, ch_hresp_in.dirty,
                                      backend=kernel_backend)
 
     # ---- 3. voluntary downgrades arrive at the home ----------------------
     ready_req = _ready(ch_req, dly_req)
-    is_vol = (ch_req.msg == int(MsgType.VOL_DOWNGRADE_I)) | \
-             (ch_req.msg == int(MsgType.VOL_DOWNGRADE_S))
-    pop_vol = ready_req & is_vol
-    dstate = dmn.absorb(
-        tables_mn, dstate, pop_vol,
-        jnp.full(pop_vol.shape, int(MnAbsorb.VOL_I), jnp.int8),
-        ch_req.dirty, ch_req.payload, backend=kernel_backend)
+    with jax.named_scope("eci.directory"):
+        is_vol = (ch_req.msg == int(MsgType.VOL_DOWNGRADE_I)) | \
+                 (ch_req.msg == int(MsgType.VOL_DOWNGRADE_S))
+        pop_vol = ready_req & is_vol
+        dstate = dmn.absorb(
+            tables_mn, dstate, pop_vol,
+            jnp.full(pop_vol.shape, int(MnAbsorb.VOL_I), jnp.int8),
+            ch_req.dirty, ch_req.payload, backend=kernel_backend)
     msg_count, payload_msgs = _count(msg_count, payload_msgs, pop_vol,
                                      ch_req.msg, ch_req.dirty,
                                      backend=kernel_backend)
@@ -471,208 +480,221 @@ def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
     vol_msg, vol_dirty = ch_req.msg, ch_req.dirty
 
     # ---- 4. arbitration: remotes AND the home compete per free line ------
-    req_ready = ready_req & ~is_vol
-    # a line is free for a new transaction only when no downgrade round-trip
-    # is outstanding AND no grant response is still in flight — otherwise a
-    # fan-out invalidation could cross the previous requester's grant (the
-    # delivered response would resurrect a sharer the directory just wrote
-    # off).  Per-line serialization, as in the 2-node engine's step 6/7.
-    resp_in_flight = tp.any_in_flight(ch_resp)
-    if packed:
-        pend_any = dmn.any_bits(_pend_or(hreq_pending), kernel_backend)
-    else:
-        pend_any = (hreq_pending != nop).any(axis=-2)
-    line_free = (st.txn_msg == nop) & ~pend_any & ~resp_in_flight
-    # The home is arbitration participant R: an outstanding want competes
-    # for the line's transaction slot like any remote request, so it
-    # bounded-waits under sustained streaming instead of waiting for the
-    # line to drain (the pre-fix unbounded starvation).
-    home_ready = want_read | want_write
-    any_req = req_ready.any(axis=-2) | home_ready
-    # Rotating priority (the ROADMAP starvation fix): the per-line pointer
-    # ``arb_rr`` names the highest-priority participant; each accepted
-    # request advances it PAST the winner, so a persistently-ready
-    # participant climbs one rank per transaction and wins within R grants
-    # — a bounded wait no fixed argmax order gives.  (Rotating by raw
-    # ``step_no`` is NOT enough: contended-line transaction latencies can
-    # align with the rotation period and park the same priority order at
-    # every free instant — the pointer rotates per GRANT, which cannot
-    # alias.)
-    ready_all = jnp.concatenate([req_ready, home_ready[..., None, :]],
-                                axis=-2)
-    if kernel_backend == "pallas":
-        from ..kernels import ops as _kops
-        winner = _kops.arb_winner(ready_all, st.arb_rr)
-    else:
-        prio = (jnp.arange(R + 1)[:, None] - st.arb_rr[..., None, :]) \
-            % (R + 1)
-        winner = jnp.argmin(jnp.where(ready_all, prio, R + 1), axis=-2)
-    accept_line = any_req & line_free
-    if home_group is not None:
-        # Fleet emulation of the folded per-home acceptance cap: lines
-        # interleave across ``home_group`` homes by address (``l % hg``),
-        # each home ranks ITS accepted lines in the folded plane's
-        # rotating order (plane position ``l // hg``, origin rotating by
-        # step), and keeps the first ``home_bw_t``.  ``home_bw_t = 0``
-        # disables the cap (rank < L+1 always holds).
-        hg = home_group
-        Lh = L // hg
-        off = st.step_no % Lh
-        h_of = lines % hg
-        rot = (lines // hg - off) % Lh
-        same = h_of[:, None] == h_of[None, :]
-        earl = rot[None, :] < rot[:, None]
-        rank = (accept_line[..., None, :] & same & earl).sum(-1)
-        cap = jnp.where(home_bw_t > 0, home_bw_t, jnp.int32(L + 1))
-        accept_line = accept_line & (rank < cap)
-    elif home_bw:
-        # Directory-slice pipeline bandwidth: each home parks at most
-        # ``home_bw`` NEW transactions per step (in-flight ones proceed
-        # unthrottled — this caps ACCEPTANCE, so it only delays, never
-        # changes, the per-line serialization the bisimulation pins).
-        # Priority rotates its origin line every step; under a fixed
-        # cumsum order a saturated low line range would starve the tail.
-        off = st.step_no % L
-        pos = (lines + off) % L
-        rolled = jnp.take(accept_line, pos, axis=-1).astype(jnp.int32)
-        rank = jnp.take(jnp.cumsum(rolled, axis=-1) - rolled,
-                        (lines - off) % L, axis=-1)
-        accept_line = accept_line & (rank < home_bw)
-    home_win = accept_line & (winner == R)
-    arb_rr = jnp.where(accept_line, (winner + 1) % (R + 1), st.arb_rr)
-    win_node = jnp.minimum(winner, R - 1)
-    win_msg = jnp.where(home_win, jnp.int8(HOME_TXN),
-                        dmn._take_remote(ch_req.msg, win_node))
-    pop_req = (accept_line & ~home_win)[..., None, :] & \
-        (rids[:, None] == winner[..., None, :])
-    ch_req = _pop(ch_req, pop_vol | (pop_req & req_ready))
-    txn_msg = jnp.where(accept_line, win_msg, st.txn_msg)
-    txn_node = jnp.where(accept_line, winner, st.txn_node)
+    with jax.named_scope("eci.arbitrate"):
+        req_ready = ready_req & ~is_vol
+        # a line is free for a new transaction only when no downgrade
+        # round-trip is outstanding AND no grant response is still in
+        # flight — otherwise a fan-out invalidation could cross the previous
+        # requester's grant (the delivered response would resurrect a sharer
+        # the directory just wrote off).  Per-line serialization, as in the
+        # 2-node engine's step 6/7.
+        resp_in_flight = tp.any_in_flight(ch_resp)
+        if packed:
+            pend_any = dmn.any_bits(_pend_or(hreq_pending), kernel_backend)
+        else:
+            pend_any = (hreq_pending != nop).any(axis=-2)
+        line_free = (st.txn_msg == nop) & ~pend_any & ~resp_in_flight
+        # The home is arbitration participant R: an outstanding want
+        # competes for the line's transaction slot like any remote request,
+        # so it bounded-waits under sustained streaming instead of waiting
+        # for the line to drain (the pre-fix unbounded starvation).
+        home_ready = want_read | want_write
+        any_req = req_ready.any(axis=-2) | home_ready
+        # Rotating priority (the ROADMAP starvation fix): the per-line
+        # pointer ``arb_rr`` names the highest-priority participant; each
+        # accepted request advances it PAST the winner, so a
+        # persistently-ready participant climbs one rank per transaction and
+        # wins within R grants — a bounded wait no fixed argmax order gives.
+        # (Rotating by raw ``step_no`` is NOT enough: contended-line
+        # transaction latencies can align with the rotation period and park
+        # the same priority order at every free instant — the pointer
+        # rotates per GRANT, which cannot alias.)
+        ready_all = jnp.concatenate([req_ready, home_ready[..., None, :]],
+                                    axis=-2)
+        if kernel_backend == "pallas":
+            from ..kernels import ops as _kops
+            winner = _kops.arb_winner(ready_all, st.arb_rr)
+        else:
+            prio = (jnp.arange(R + 1)[:, None] - st.arb_rr[..., None, :]) \
+                % (R + 1)
+            winner = jnp.argmin(jnp.where(ready_all, prio, R + 1), axis=-2)
+        accept_line = any_req & line_free
+        if home_group is not None:
+            # Fleet emulation of the folded per-home acceptance cap: lines
+            # interleave across ``home_group`` homes by address
+            # (``l % hg``), each home ranks ITS accepted lines in the folded
+            # plane's
+            # rotating order (plane position ``l // hg``, origin rotating by
+            # step), and keeps the first ``home_bw_t``.  ``home_bw_t = 0``
+            # disables the cap (rank < L+1 always holds).
+            hg = home_group
+            Lh = L // hg
+            off = st.step_no % Lh
+            h_of = lines % hg
+            rot = (lines // hg - off) % Lh
+            same = h_of[:, None] == h_of[None, :]
+            earl = rot[None, :] < rot[:, None]
+            rank = (accept_line[..., None, :] & same & earl).sum(-1)
+            cap = jnp.where(home_bw_t > 0, home_bw_t, jnp.int32(L + 1))
+            accept_line = accept_line & (rank < cap)
+        elif home_bw:
+            # Directory-slice pipeline bandwidth: each home parks at most
+            # ``home_bw`` NEW transactions per step (in-flight ones proceed
+            # unthrottled — this caps ACCEPTANCE, so it only delays, never
+            # changes, the per-line serialization the bisimulation pins).
+            # Priority rotates its origin line every step; under a fixed
+            # cumsum order a saturated low line range would starve the
+            # tail.
+            off = st.step_no % L
+            pos = (lines + off) % L
+            rolled = jnp.take(accept_line, pos, axis=-1).astype(jnp.int32)
+            rank = jnp.take(jnp.cumsum(rolled, axis=-1) - rolled,
+                            (lines - off) % L, axis=-1)
+            accept_line = accept_line & (rank < home_bw)
+        home_win = accept_line & (winner == R)
+        arb_rr = jnp.where(accept_line, (winner + 1) % (R + 1), st.arb_rr)
+        win_node = jnp.minimum(winner, R - 1)
+        win_msg = jnp.where(home_win, jnp.int8(HOME_TXN),
+                            dmn._take_remote(ch_req.msg, win_node))
+        pop_req = (accept_line & ~home_win)[..., None, :] & \
+            (rids[:, None] == winner[..., None, :])
+        ch_req = _pop(ch_req, pop_vol | (pop_req & req_ready))
+        txn_msg = jnp.where(accept_line, win_msg, st.txn_msg)
+        txn_node = jnp.where(accept_line, winner, st.txn_node)
     msg_count, payload_msgs = _count(
         msg_count, payload_msgs, accept_line & ~home_win, win_msg,
         jnp.zeros(accept_line.shape, bool), backend=kernel_backend)
 
     # ---- 5. fan-out: emit one HOME_DOWNGRADE_* per conflicting sharer ----
-    active_txn = txn_msg != nop
-    is_home_txn = txn_msg == HOME_TXN
-    # the home's participant id R is clamped for view/table gathers; every
-    # use is masked by ~is_home_txn (or by resp == NOP, which home
-    # transactions never produce).
-    node_c = jnp.minimum(txn_node, R - 1)
-    # an UPGRADE whose requester was concurrently invalidated is doomed to
-    # a NACK — suppress its fan-out so the new owner keeps the line.
-    req_view_now = dmn.view_of(dstate, node_c)
-    doomed = active_txn & (txn_msg == int(MsgType.REQ_UPGRADE)) & \
-        (req_view_now != int(RemoteView.S))
-    if packed:
-        # fan-out sets as word planes: recall (HD_S) / invalidate (HD_I)
-        # targets are one AND-NOT-hot each over the presence/exclusive
-        # planes, then widened to the dense [R, L] lane mask the (dense)
-        # transport submit needs.  The planes are per-line disjoint, so
-        # the HD_S-wins combine below matches the dense expression.
-        ns_w, ni_w = dmn.needed_words(
-            dstate, active_txn & ~doomed & ~is_home_txn, txn_msg, node_c,
-            kernel_backend)
-        nsh_w, nih_w = dmn.home_needed_words(
-            dstate, want_read & is_home_txn, want_write & is_home_txn)
-        iht = is_home_txn[..., None]
-        need_s_w = jnp.where(iht, nsh_w, ns_w)
-        need_i_w = jnp.where(iht, nih_w, ni_w)
-        needed = jnp.where(
-            dmn.unpack_mask(need_s_w, R),
-            jnp.int8(int(MsgType.HOME_DOWNGRADE_S)),
-            jnp.where(dmn.unpack_mask(need_i_w, R),
-                      jnp.int8(int(MsgType.HOME_DOWNGRADE_I)), nop))
-        send_h = (needed != nop) & \
-            ~dmn.unpack_mask(_pend_or(hreq_pending), R)
-    else:
-        needed_r = dmn.needed_downgrades(
-            dstate, active_txn & ~doomed & ~is_home_txn, txn_msg, node_c)
-        # a parked HOME transaction fans out through the SAME machinery:
-        # reads recall a dirty owner to S, writes invalidate every sharer.
-        needed_h = dmn.home_needed_downgrades(
-            dstate, want_read & is_home_txn, want_write & is_home_txn)
-        needed = jnp.where(is_home_txn[..., None, :], needed_h, needed_r)
-        send_h = (needed != nop) & (hreq_pending == nop)
-    ch_hreq, acc_h = tp.submit(ch_hreq, tp.CLASS_HOME_REQ, send_h, needed,
-                               jnp.zeros(send_h.shape, bool),
-                               jnp.zeros_like(st.ch_hreq.payload), credits,
-                               shared=hreq_shared,
-                               backend=kernel_backend)
-    if packed:
-        # acc_h ⊆ send_h ⊆ pending-free, and every accepted lane sits in
-        # exactly one of the two word planes — OR-in is the masked store.
-        acc_w = dmn.pack_mask(acc_h)
-        hreq_pending = jnp.stack(
-            [hreq_pending[..., 0, :, :] | (acc_w & need_s_w),
-             hreq_pending[..., 1, :, :] | (acc_w & need_i_w)], axis=-3)
-    else:
-        hreq_pending = jnp.where(acc_h, needed, hreq_pending)
+    with jax.named_scope("eci.directory"):
+        active_txn = txn_msg != nop
+        is_home_txn = txn_msg == HOME_TXN
+        # the home's participant id R is clamped for view/table gathers;
+        # every use is masked by ~is_home_txn (or by resp == NOP, which home
+        # transactions never produce).
+        node_c = jnp.minimum(txn_node, R - 1)
+        # an UPGRADE whose requester was concurrently invalidated is doomed
+        # to a NACK — suppress its fan-out so the new owner keeps the line.
+        req_view_now = dmn.view_of(dstate, node_c)
+        doomed = active_txn & (txn_msg == int(MsgType.REQ_UPGRADE)) & \
+            (req_view_now != int(RemoteView.S))
+        if packed:
+            # fan-out sets as word planes: recall (HD_S) / invalidate
+            # (HD_I) targets are one AND-NOT-hot each over the
+            # presence/exclusive planes, then widened to the dense [R, L]
+            # lane mask the (dense) transport submit needs.  The planes are
+            # per-line disjoint, so the HD_S-wins combine below matches the
+            # dense expression.
+            ns_w, ni_w = dmn.needed_words(
+                dstate, active_txn & ~doomed & ~is_home_txn, txn_msg,
+                node_c, kernel_backend)
+            nsh_w, nih_w = dmn.home_needed_words(
+                dstate, want_read & is_home_txn, want_write & is_home_txn)
+            iht = is_home_txn[..., None]
+            need_s_w = jnp.where(iht, nsh_w, ns_w)
+            need_i_w = jnp.where(iht, nih_w, ni_w)
+            needed = jnp.where(
+                dmn.unpack_mask(need_s_w, R),
+                jnp.int8(int(MsgType.HOME_DOWNGRADE_S)),
+                jnp.where(dmn.unpack_mask(need_i_w, R),
+                          jnp.int8(int(MsgType.HOME_DOWNGRADE_I)), nop))
+            send_h = (needed != nop) & \
+                ~dmn.unpack_mask(_pend_or(hreq_pending), R)
+        else:
+            needed_r = dmn.needed_downgrades(
+                dstate, active_txn & ~doomed & ~is_home_txn, txn_msg,
+                node_c)
+            # a parked HOME transaction fans out through the SAME
+            # machinery: reads recall a dirty owner to S, writes invalidate
+            # every sharer.
+            needed_h = dmn.home_needed_downgrades(
+                dstate, want_read & is_home_txn, want_write & is_home_txn)
+            needed = jnp.where(is_home_txn[..., None, :], needed_h,
+                               needed_r)
+            send_h = (needed != nop) & (hreq_pending == nop)
+        ch_hreq, acc_h = tp.submit(ch_hreq, tp.CLASS_HOME_REQ, send_h,
+                                   needed, jnp.zeros(send_h.shape, bool),
+                                   jnp.zeros_like(st.ch_hreq.payload),
+                                   credits, shared=hreq_shared,
+                                   backend=kernel_backend)
+        if packed:
+            # acc_h ⊆ send_h ⊆ pending-free, and every accepted lane sits
+            # in exactly one of the two word planes — OR-in is the masked
+            # store.
+            acc_w = dmn.pack_mask(acc_h)
+            hreq_pending = jnp.stack(
+                [hreq_pending[..., 0, :, :] | (acc_w & need_s_w),
+                 hreq_pending[..., 1, :, :] | (acc_w & need_i_w)], axis=-3)
+        else:
+            hreq_pending = jnp.where(acc_h, needed, hreq_pending)
 
     # ---- 6. grant parked requests whose preconditions now hold -----------
-    in_flight_vol = ((ch_req.msg == int(MsgType.VOL_DOWNGRADE_I)) |
-                     (ch_req.msg == int(MsgType.VOL_DOWNGRADE_S))
-                     ).any(axis=-2)
-    in_flight_h = tp.any_in_flight(ch_hreq) | tp.any_in_flight(ch_hresp)
-    # `needed` must be EMPTY, not merely pending-free: a fan-out submission
-    # refused for credit leaves hreq_pending == NOP with the sharer's view
-    # intact — granting then would hand out exclusivity while the line is
-    # still shared.  (Home transactions complete under the same guard.)
-    if packed:
-        complete = active_txn & \
-            ~dmn.any_bits(need_s_w | need_i_w, kernel_backend) & \
-            ~dmn.any_bits(_pend_or(hreq_pending), kernel_backend) & \
-            ~in_flight_vol & ~in_flight_h
-    else:
-        complete = active_txn & ~(needed != nop).any(axis=-2) & \
-            ~(hreq_pending != nop).any(axis=-2) & \
-            ~in_flight_vol & ~in_flight_h
-    complete_r = complete & ~is_home_txn
-    dstate, resp, resp_pay = dmn.grant(tables_mn, dstate, complete_r,
-                                       txn_msg, node_c)
-    # a completed HOME transaction services the access in place: the read
-    # serves the coherent line value, the write lands through the home
-    # tables — no message leaves the home.
-    complete_h = complete & is_home_txn
-    hread_done = complete_h & want_read
-    hread_val = jnp.where(hread_done[..., None], dmn.home_value(dstate), 0)
-    dstate = dmn.home_apply_write(dstate, complete_h & want_write, wv)
-    want_read2 = want_read & ~complete_h
-    want_write2 = want_write & ~complete_h
-    txn_msg = jnp.where(complete, nop, txn_msg)
-    send_resp = (rids[:, None] == txn_node[..., None, :]) & \
-        (resp != nop)[..., None, :]
-    ch_resp, _ = tp.submit(ch_resp, tp.CLASS_HOME_RESP, send_resp,
-                           jnp.broadcast_to(resp[..., None, :],
-                                            send_resp.shape),
-                           jnp.zeros(send_resp.shape, bool),
-                           jnp.broadcast_to(resp_pay[..., None, :, :],
-                                            send_resp.shape
-                                            + resp_pay.shape[-1:]),
-                           credits, unbounded=True)
-    carries = (resp == int(MsgType.RESP_DATA)) | \
-              (resp == int(MsgType.RESP_DATA_DIRTY))
-    msg_count, payload_msgs = _count(msg_count, payload_msgs,
-                                     resp != nop, resp, carries,
-                                     backend=kernel_backend)
+    with jax.named_scope("eci.directory"):
+        in_flight_vol = ((ch_req.msg == int(MsgType.VOL_DOWNGRADE_I)) |
+                         (ch_req.msg == int(MsgType.VOL_DOWNGRADE_S))
+                         ).any(axis=-2)
+        in_flight_h = tp.any_in_flight(ch_hreq) | tp.any_in_flight(ch_hresp)
+        # `needed` must be EMPTY, not merely pending-free: a fan-out submission
+        # refused for credit leaves hreq_pending == NOP with the sharer's view
+        # intact — granting then would hand out exclusivity while the line is
+        # still shared.  (Home transactions complete under the same guard.)
+        if packed:
+            complete = active_txn & \
+                ~dmn.any_bits(need_s_w | need_i_w, kernel_backend) & \
+                ~dmn.any_bits(_pend_or(hreq_pending), kernel_backend) & \
+                ~in_flight_vol & ~in_flight_h
+        else:
+            complete = active_txn & ~(needed != nop).any(axis=-2) & \
+                ~(hreq_pending != nop).any(axis=-2) & \
+                ~in_flight_vol & ~in_flight_h
+        complete_r = complete & ~is_home_txn
+        dstate, resp, resp_pay = dmn.grant(tables_mn, dstate, complete_r,
+                                           txn_msg, node_c)
+        # a completed HOME transaction services the access in place: the read
+        # serves the coherent line value, the write lands through the home
+        # tables — no message leaves the home.
+        complete_h = complete & is_home_txn
+        hread_done = complete_h & want_read
+        hread_val = jnp.where(hread_done[..., None], dmn.home_value(dstate), 0)
+        dstate = dmn.home_apply_write(dstate, complete_h & want_write, wv)
+        want_read2 = want_read & ~complete_h
+        want_write2 = want_write & ~complete_h
+        txn_msg = jnp.where(complete, nop, txn_msg)
+        send_resp = (rids[:, None] == txn_node[..., None, :]) & \
+            (resp != nop)[..., None, :]
+        ch_resp, _ = tp.submit(ch_resp, tp.CLASS_HOME_RESP, send_resp,
+                               jnp.broadcast_to(resp[..., None, :],
+                                                send_resp.shape),
+                               jnp.zeros(send_resp.shape, bool),
+                               jnp.broadcast_to(resp_pay[..., None, :, :],
+                                                send_resp.shape
+                                                + resp_pay.shape[-1:]),
+                               credits, unbounded=True)
+        carries = (resp == int(MsgType.RESP_DATA)) | \
+                  (resp == int(MsgType.RESP_DATA_DIRTY))
+        msg_count, payload_msgs = _count(msg_count, payload_msgs,
+                                         resp != nop, resp, carries,
+                                         backend=kernel_backend)
 
     # ---- 7. grant responses arrive at the remotes ------------------------
     ch_resp_in = ch_resp
     ch_resp, r_arr = tp.deliver(ch_resp, tp.CLASS_HOME_RESP, delays,
                                 delay_l=dly_resp)
-    was_load = st.agents.pending_op == int(LocalOp.LOAD)
-    agents, _nack = ag.on_response(tables, st.agents, r_arr,
-                                   ch_resp_in.msg, ch_resp_in.payload,
-                                   nack_holds=True)
-    load_done = r_arr & was_load & ~_nack
-    load_val = jnp.where(load_done[..., None], agents.cache, 0)
+    with jax.named_scope("eci.agents"):
+        was_load = st.agents.pending_op == int(LocalOp.LOAD)
+        agents, _nack = ag.on_response(tables, st.agents, r_arr,
+                                       ch_resp_in.msg, ch_resp_in.payload,
+                                       nack_holds=True)
+        load_done = r_arr & was_load & ~_nack
+        load_val = jnp.where(load_done[..., None], agents.cache, 0)
 
     # ---- 8. home-initiated downgrades arrive at the remotes --------------
     ch_hreq_in = ch_hreq
     ch_hreq, h_arr = tp.deliver(ch_hreq, tp.CLASS_HOME_REQ, delays,
                                 delay_l=dly_hreq)
-    agents, hresp, hresp_dirty, hresp_pay = ag.on_home_msg(
-        tables, agents, h_arr, ch_hreq_in.msg)
+    with jax.named_scope("eci.agents"):
+        agents, hresp, hresp_dirty, hresp_pay = ag.on_home_msg(
+            tables, agents, h_arr, ch_hreq_in.msg)
     msg_count, payload_msgs = _count(msg_count, payload_msgs, h_arr,
                                      ch_hreq_in.msg,
                                      jnp.zeros(h_arr.shape, bool),
@@ -682,45 +704,46 @@ def step_mn(tables: DenseTables, tables_mn: DenseTablesMN,
                             unbounded=True)
 
     # ---- 9. remotes submit local ops (fresh + parked retries) ------------
-    if packed:
-        locked = dmn.unpack_mask(_pend_or(hreq_pending), R) | \
-            (ch_hreq.msg != nop)
-    else:
-        locked = (hreq_pending != nop) | (ch_hreq.msg != nop)
-    parked = (agents.pending_op != int(LocalOp.NOP)) & \
-             (agents.pending_req == nop)
-    eff_op = jnp.where(parked, agents.pending_op, op)
-    eff_op = jnp.where(locked, jnp.int8(int(LocalOp.NOP)), eff_op)
-    # mask ops outside the subset's MN envelope (DEMOTE always — see the
-    # module docstring — plus whatever the subset's guarantee excludes;
-    # the public APIs reject such programs loudly BEFORE they get here).
-    op_ok = lookup(tables_mn.op_ok, eff_op)
-    eff_op = jnp.where(op_ok, eff_op, jnp.int8(int(LocalOp.NOP)))
-    # An op that would emit a message stalls until the transport CAN take
-    # it (slot + credit) — the dirty-eviction drop guard of
-    # engine.stall_unready_ops, with the credit ranking computed ONCE: the
-    # real emission set below is a subset of these candidates on unchanged
-    # occupancy (ranks only shrink), so the dry-run verdict IS the final
-    # acceptance and the channel write needs no second ranking.
-    o = eff_op.astype(jnp.int32)
-    rs = agents.remote_state.astype(jnp.int32)
-    req_of = lookup(tables.loc_request, o, rs).astype(jnp.int8)
-    would_emit = req_of != nop
-    acc_pre = tp.credit_accept(ch_req, tp.CLASS_REMOTE_REQ,
-                               would_emit & (ch_req.msg == nop), credits,
-                               backend=kernel_backend)
-    eff_op = jnp.where(would_emit & ~acc_pre, jnp.int8(int(LocalOp.NOP)),
-                       eff_op)
-    eff_val = jnp.where(parked[..., None], agents.pending_val, op_val)
-    agents2, accepted, emit, req_dirty, req_pay = ag.submit(
-        tables, agents, eff_op, eff_val)
-    ch_req = tp.place(ch_req, emit != nop, emit, req_dirty, req_pay)
-    # load hits retire immediately.
-    o = eff_op.astype(jnp.int32)
-    hit = lookup(tables.loc_hit, o, rs)
-    load_hit = accepted & hit & (o == int(LocalOp.LOAD))
-    load_done = load_done | load_hit
-    load_val = jnp.where(load_hit[..., None], agents2.cache, load_val)
+    with jax.named_scope("eci.agents"):
+        if packed:
+            locked = dmn.unpack_mask(_pend_or(hreq_pending), R) | \
+                (ch_hreq.msg != nop)
+        else:
+            locked = (hreq_pending != nop) | (ch_hreq.msg != nop)
+        parked = (agents.pending_op != int(LocalOp.NOP)) & \
+                 (agents.pending_req == nop)
+        eff_op = jnp.where(parked, agents.pending_op, op)
+        eff_op = jnp.where(locked, jnp.int8(int(LocalOp.NOP)), eff_op)
+        # mask ops outside the subset's MN envelope (DEMOTE always — see the
+        # module docstring — plus whatever the subset's guarantee excludes;
+        # the public APIs reject such programs loudly BEFORE they get here).
+        op_ok = lookup(tables_mn.op_ok, eff_op)
+        eff_op = jnp.where(op_ok, eff_op, jnp.int8(int(LocalOp.NOP)))
+        # An op that would emit a message stalls until the transport CAN take
+        # it (slot + credit) — the dirty-eviction drop guard of
+        # engine.stall_unready_ops, with the credit ranking computed ONCE: the
+        # real emission set below is a subset of these candidates on unchanged
+        # occupancy (ranks only shrink), so the dry-run verdict IS the final
+        # acceptance and the channel write needs no second ranking.
+        o = eff_op.astype(jnp.int32)
+        rs = agents.remote_state.astype(jnp.int32)
+        req_of = lookup(tables.loc_request, o, rs).astype(jnp.int8)
+        would_emit = req_of != nop
+        acc_pre = tp.credit_accept(ch_req, tp.CLASS_REMOTE_REQ,
+                                   would_emit & (ch_req.msg == nop), credits,
+                                   backend=kernel_backend)
+        eff_op = jnp.where(would_emit & ~acc_pre, jnp.int8(int(LocalOp.NOP)),
+                           eff_op)
+        eff_val = jnp.where(parked[..., None], agents.pending_val, op_val)
+        agents2, accepted, emit, req_dirty, req_pay = ag.submit(
+            tables, agents, eff_op, eff_val)
+        ch_req = tp.place(ch_req, emit != nop, emit, req_dirty, req_pay)
+        # load hits retire immediately.
+        o = eff_op.astype(jnp.int32)
+        hit = lookup(tables.loc_hit, o, rs)
+        load_hit = accepted & hit & (o == int(LocalOp.LOAD))
+        load_done = load_done | load_hit
+        load_val = jnp.where(load_hit[..., None], agents2.cache, load_val)
 
     new = EngineMNState(
         dir=dstate, agents=agents2,

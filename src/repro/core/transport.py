@@ -131,34 +131,37 @@ def credit_accept(ch: Channel, msg_class: int, cand: jnp.ndarray,
     to the default XLA expressions (integer arithmetic); the shared-pool
     path always uses the jnp expressions.
     """
-    L = ch.msg.shape[-1]
-    odd = (jnp.arange(L) & 1).astype(bool)                      # [L]
-    active = ch.msg != int(MsgType.NOP)
-    if shared and ch.msg.ndim > 1:
-        c_o = jnp.where(odd, cand, False).astype(jnp.int32)
-        c_e = jnp.where(odd, False, cand).astype(jnp.int32)
-        occ_o = jnp.where(odd, active, False).sum(
-            axis=(-2, -1), keepdims=True)
-        occ_e = jnp.where(odd, False, active).sum(
-            axis=(-2, -1), keepdims=True)
-        flat_o = c_o.reshape(c_o.shape[:-2] + (-1,))
-        flat_e = c_e.reshape(c_e.shape[:-2] + (-1,))
-        rank_o = (jnp.cumsum(flat_o, axis=-1) - flat_o).reshape(cand.shape)
-        rank_e = (jnp.cumsum(flat_e, axis=-1) - flat_e).reshape(cand.shape)
-        occ_rank = jnp.where(odd, occ_o + rank_o, occ_e + rank_e)
-    elif backend == "pallas":
-        from ..kernels import ops as _kops
-        occ_rank = _kops.credit_rank(active, cand)
-    else:
-        c_o = jnp.where(odd, cand, False).astype(jnp.int32)
-        c_e = jnp.where(odd, False, cand).astype(jnp.int32)
-        occ_o = jnp.where(odd, active, False).sum(-1, keepdims=True)
-        occ_e = jnp.where(odd, False, active).sum(-1, keepdims=True)
-        rank_o = jnp.cumsum(c_o, axis=-1) - c_o    # candidates before me
-        rank_e = jnp.cumsum(c_e, axis=-1) - c_e
-        occ_rank = jnp.where(odd, occ_o + rank_o, occ_e + rank_e)
-    vc_credit = vc_value(credits, jnp.arange(L), msg_class)     # [L]
-    return cand & (occ_rank < vc_credit)
+    with jax.named_scope("eci.credit_rank"):
+        L = ch.msg.shape[-1]
+        odd = (jnp.arange(L) & 1).astype(bool)                  # [L]
+        active = ch.msg != int(MsgType.NOP)
+        if shared and ch.msg.ndim > 1:
+            c_o = jnp.where(odd, cand, False).astype(jnp.int32)
+            c_e = jnp.where(odd, False, cand).astype(jnp.int32)
+            occ_o = jnp.where(odd, active, False).sum(
+                axis=(-2, -1), keepdims=True)
+            occ_e = jnp.where(odd, False, active).sum(
+                axis=(-2, -1), keepdims=True)
+            flat_o = c_o.reshape(c_o.shape[:-2] + (-1,))
+            flat_e = c_e.reshape(c_e.shape[:-2] + (-1,))
+            rank_o = (jnp.cumsum(flat_o, axis=-1)
+                      - flat_o).reshape(cand.shape)
+            rank_e = (jnp.cumsum(flat_e, axis=-1)
+                      - flat_e).reshape(cand.shape)
+            occ_rank = jnp.where(odd, occ_o + rank_o, occ_e + rank_e)
+        elif backend == "pallas":
+            from ..kernels import ops as _kops
+            occ_rank = _kops.credit_rank(active, cand)
+        else:
+            c_o = jnp.where(odd, cand, False).astype(jnp.int32)
+            c_e = jnp.where(odd, False, cand).astype(jnp.int32)
+            occ_o = jnp.where(odd, active, False).sum(-1, keepdims=True)
+            occ_e = jnp.where(odd, False, active).sum(-1, keepdims=True)
+            rank_o = jnp.cumsum(c_o, axis=-1) - c_o    # candidates before me
+            rank_e = jnp.cumsum(c_e, axis=-1) - c_e
+            occ_rank = jnp.where(odd, occ_o + rank_o, occ_e + rank_e)
+        vc_credit = vc_value(credits, jnp.arange(L), msg_class)  # [L]
+        return cand & (occ_rank < vc_credit)
 
 
 def place(ch: Channel, accept: jnp.ndarray, msg: jnp.ndarray,
@@ -169,12 +172,13 @@ def place(ch: Channel, accept: jnp.ndarray, msg: jnp.ndarray,
     earlier in the step (and whose final emission set can only have SHRUNK
     since — fewer candidates means smaller ranks on unchanged occupancy)
     reuses that verdict instead of ranking a second time."""
-    return Channel(
-        msg=jnp.where(accept, msg.astype(jnp.int8), ch.msg),
-        dirty=jnp.where(accept, dirty, ch.dirty),
-        payload=jnp.where(accept[..., None], payload, ch.payload),
-        age=jnp.where(accept, 0, ch.age),
-    )
+    with jax.named_scope("eci.transport"):
+        return Channel(
+            msg=jnp.where(accept, msg.astype(jnp.int8), ch.msg),
+            dirty=jnp.where(accept, dirty, ch.dirty),
+            payload=jnp.where(accept[..., None], payload, ch.payload),
+            age=jnp.where(accept, 0, ch.age),
+        )
 
 
 def submit(ch: Channel, msg_class: int, want: jnp.ndarray, msg: jnp.ndarray,
@@ -198,18 +202,19 @@ def submit(ch: Channel, msg_class: int, want: jnp.ndarray, msg: jnp.ndarray,
     the occupancy/rank computation every step.  ``shared=True`` accounts
     credits across all leading axes (see ``credit_accept``).
     """
-    free = ch.msg == int(MsgType.NOP)
-    cand = want & free                                          # [..., L]
-    accept = cand if unbounded else credit_accept(ch, msg_class, cand,
-                                                  credits, shared=shared,
-                                                  backend=backend)
-    return place(ch, accept, msg, dirty, payload), accept
+    with jax.named_scope("eci.transport"):
+        free = ch.msg == int(MsgType.NOP)
+        cand = want & free                                      # [..., L]
+        accept = cand if unbounded else credit_accept(
+            ch, msg_class, cand, credits, shared=shared, backend=backend)
+        return place(ch, accept, msg, dirty, payload), accept
 
 
 def tick(ch: Channel) -> Channel:
     """Advance time for all in-flight messages."""
-    active = ch.msg != int(MsgType.NOP)
-    return ch._replace(age=jnp.where(active, ch.age + 1, ch.age))
+    with jax.named_scope("eci.transport"):
+        active = ch.msg != int(MsgType.NOP)
+        return ch._replace(age=jnp.where(active, ch.age + 1, ch.age))
 
 
 def any_in_flight(ch: Channel) -> jnp.ndarray:
@@ -230,9 +235,11 @@ def deliver(ch: Channel, msg_class: int, delays: jnp.ndarray,
     caller — the engines hoist one gather per VC pair out of the per-site
     bodies of their fused steps.
     """
-    if delay_l is None:
-        delay_l = vc_value(delays, jnp.arange(ch.msg.shape[-1]), msg_class)
-    ready = (ch.msg != int(MsgType.NOP)) & (ch.age >= delay_l)
-    freed = ch._replace(msg=jnp.where(ready, int(MsgType.NOP),
-                                      ch.msg).astype(jnp.int8))
-    return freed, ready
+    with jax.named_scope("eci.transport"):
+        if delay_l is None:
+            delay_l = vc_value(delays, jnp.arange(ch.msg.shape[-1]),
+                               msg_class)
+        ready = (ch.msg != int(MsgType.NOP)) & (ch.age >= delay_l)
+        freed = ch._replace(msg=jnp.where(ready, int(MsgType.NOP),
+                                          ch.msg).astype(jnp.int8))
+        return freed, ready

@@ -55,6 +55,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.engine_mn import EngineMN, EngineMNState, busy_flag_mn, step_mn
 from ..core.messages import MsgType
@@ -213,162 +214,167 @@ def _jitted_stream(subset_name: str, collect_trace: bool, width: int,
 
         def body(c, t):
             # ---- fetch each remote's issue window -----------------------
-            idx = c.cursor[:, None] + wr[None, :]            # [R, W]
-            active = idx < T
-            if fleet:
-                # window slots past the member's real width never
-                # activate — the member behaves exactly as if its window
-                # were width_cap wide while the fleet compiles one W-max
-                # shaped program.
-                active = active & (wr[None, :] < width_cap)
-            idxc = jnp.minimum(idx, T - 1)
-            s_op = wl_op[idxc, ar[:, None]]                  # [R, W]
-            s_line = wl_line[idxc, ar[:, None]]
-            s_val = wl_value[idxc, ar[:, None]].astype(dt)
-            is_nop = s_op == nop_op
-            pending = active & ~c.issued
-            real = pending & ~is_nop
-            # one MSHR per (remote, line): a slot is serialized in-queue
-            # behind an EARLIER un-issued slot on the same line, and held
-            # while the remote still has a transaction in flight there.
-            # The conflict mask deliberately uses ALL queued real slots
-            # (arrived or not) so per-line program order survives any
-            # arrival schedule.
-            same = s_line[:, :, None] == s_line[:, None, :]  # [R, Wk, Wj]
-            earlier = wr[None, :] < wr[:, None]              # [Wk, Wj] j<k
-            conflict = (real[:, None, :] & same &
-                        earlier[None]).any(-1)               # [R, W]
-            line_busy = c.outstanding[ar[:, None], s_line]
-            if open_loop:
-                # ---- continuous-batching admission --------------------
-                # a slot is a candidate only once its stamp has ARRIVED;
-                # with a batch cap, the FIFO-by-arrival-stamp earliest
-                # candidates fill the budget the reserve watermark leaves
-                # open (rtp-llm FIFOScheduler style) — admission gates
-                # WHEN, never WHAT, so the oracle replay stays exact.
-                s_arr = arr_step[idxc, ar[:, None]]          # [R, W]
-                arrived = s_arr <= t
-                ready = real & arrived & ~conflict & ~line_busy
-                if admit_cap:
-                    inflight = c.outstanding.sum().astype(jnp.int32)
-                    budget = jnp.maximum(
-                        admit_cap - admit_reserve - inflight, 0)
-                    # stable argsort = FIFO by stamp, program order on
-                    # ties; non-candidates sort to the back.
-                    key = jnp.where(ready, s_arr,
-                                    jnp.iinfo(jnp.int32).max).ravel()
-                    order = jnp.argsort(key, stable=True)
-                    rank = jnp.zeros_like(order).at[order].set(
-                        jnp.arange(R * W))
-                    can = ready & (rank.reshape(R, W) < budget)
+            with jax.named_scope("eci.issue"):
+                idx = c.cursor[:, None] + wr[None, :]            # [R, W]
+                active = idx < T
+                if fleet:
+                    # window slots past the member's real width never
+                    # activate — the member behaves exactly as if its window
+                    # were width_cap wide while the fleet compiles one W-max
+                    # shaped program.
+                    active = active & (wr[None, :] < width_cap)
+                idxc = jnp.minimum(idx, T - 1)
+                s_op = wl_op[idxc, ar[:, None]]                  # [R, W]
+                s_line = wl_line[idxc, ar[:, None]]
+                s_val = wl_value[idxc, ar[:, None]].astype(dt)
+                is_nop = s_op == nop_op
+                pending = active & ~c.issued
+                real = pending & ~is_nop
+                # one MSHR per (remote, line): a slot is serialized in-queue
+                # behind an EARLIER un-issued slot on the same line, and held
+                # while the remote still has a transaction in flight there.
+                # The conflict mask deliberately uses ALL queued real slots
+                # (arrived or not) so per-line program order survives any
+                # arrival schedule.
+                same = s_line[:, :, None] == s_line[:, None, :]  # [R, Wk, Wj]
+                earlier = wr[None, :] < wr[:, None]              # [Wk, Wj] j<k
+                conflict = (real[:, None, :] & same &
+                            earlier[None]).any(-1)               # [R, W]
+                line_busy = c.outstanding[ar[:, None], s_line]
+                if open_loop:
+                    # ---- continuous-batching admission ----------------
+                    # a slot is a candidate only once its stamp has ARRIVED;
+                    # with a batch cap, the FIFO-by-arrival-stamp earliest
+                    # candidates fill the budget the reserve watermark leaves
+                    # open (rtp-llm FIFOScheduler style) — admission gates
+                    # WHEN, never WHAT, so the oracle replay stays exact.
+                    s_arr = arr_step[idxc, ar[:, None]]          # [R, W]
+                    arrived = s_arr <= t
+                    ready = real & arrived & ~conflict & ~line_busy
+                    if admit_cap:
+                        inflight = c.outstanding.sum().astype(jnp.int32)
+                        budget = jnp.maximum(
+                            admit_cap - admit_reserve - inflight, 0)
+                        # stable argsort = FIFO by stamp, program order on
+                        # ties; non-candidates sort to the back.
+                        key = jnp.where(ready, s_arr,
+                                        jnp.iinfo(jnp.int32).max).ravel()
+                        order = jnp.argsort(key, stable=True)
+                        rank = jnp.zeros_like(order).at[order].set(
+                            jnp.arange(R * W))
+                        can = ready & (rank.reshape(R, W) < budget)
+                    else:
+                        can = ready
                 else:
-                    can = ready
-            else:
-                can = real & ~conflict & ~line_busy
-            # scatter the issuable slots into the dense [R, L] op plane —
-            # additive scatter: at most one slot per (remote, line)
-            # contributes a non-zero, the rest add NOP/zero.
-            opd = jnp.zeros((R, L), jnp.int8).at[ar[:, None], s_line].add(
-                jnp.where(can, s_op, nop_op))
-            vald = jnp.zeros((R, L, B), dt).at[ar[:, None], s_line].add(
-                jnp.where(can, s_val, 0)[:, :, None])
-            born_d = jnp.zeros((R, L), jnp.int32).at[
-                ar[:, None], s_line].add(jnp.where(can, c.slot_born, 0))
-            if open_loop:   # arrival stamp rides along for sojourn
-                soj_d = jnp.zeros((R, L), jnp.int32).at[
-                    ar[:, None], s_line].add(jnp.where(can, s_arr, 0))
+                    can = real & ~conflict & ~line_busy
+                # scatter the issuable slots into the dense [R, L] op plane —
+                # additive scatter: at most one slot per (remote, line)
+                # contributes a non-zero, the rest add NOP/zero.
+                opd = jnp.zeros((R, L), jnp.int8).at[ar[:, None], s_line].add(
+                    jnp.where(can, s_op, nop_op))
+                vald = jnp.zeros((R, L, B), dt).at[ar[:, None], s_line].add(
+                    jnp.where(can, s_val, 0)[:, :, None])
+                born_d = jnp.zeros((R, L), jnp.int32).at[
+                    ar[:, None], s_line].add(jnp.where(can, c.slot_born, 0))
+                if open_loop:   # arrival stamp rides along for sojourn
+                    soj_d = jnp.zeros((R, L), jnp.int32).at[
+                        ar[:, None], s_line].add(jnp.where(can, s_arr, 0))
 
             # ---- one engine step under sustained traffic ----------------
-            hk = {"home_group": home_group,
-                  "home_bw_t": home_bw_t} if fleet else {}
-            if obs is None:
-                st2, out = step_fn(c.st, opd, vald, zb, zb, zwv, delays,
-                                   credits, **hk)
-            else:
-                st2, out, ev = step_fn(c.st, opd, vald, zb, zb, zwv,
-                                       delays, credits, emit_events=True,
-                                       **hk)
+            with jax.named_scope("eci.step"):
+                hk = {"home_group": home_group,
+                      "home_bw_t": home_bw_t} if fleet else {}
+                if obs is None:
+                    st2, out = step_fn(c.st, opd, vald, zb, zb, zwv, delays,
+                                       credits, **hk)
+                else:
+                    st2, out, ev = step_fn(c.st, opd, vald, zb, zb, zwv,
+                                           delays, credits, emit_events=True,
+                                           **hk)
 
             # ---- adopt newly accepted ops, detect retirements -----------
-            newly = out.accepted                       # [R, L]
-            outstanding = c.outstanding | newly
-            born = jnp.where(newly, born_d, c.born)
-            # retired once the MSHR is clear again: hits the same step,
-            # misses when the grant (or NACK-retry grant) lands.
-            mshr_free = (st2.agents.pending_op == int(LocalOp.NOP)) & \
-                        (st2.agents.pending_req == int(MsgType.NOP))
-            retired = outstanding & mshr_free
-            outstanding = outstanding & ~retired
+            with jax.named_scope("eci.retire"):
+                newly = out.accepted                       # [R, L]
+                outstanding = c.outstanding | newly
+                born = jnp.where(newly, born_d, c.born)
+                # retired once the MSHR is clear again: hits the same step,
+                # misses when the grant (or NACK-retry grant) lands.
+                mshr_free = (st2.agents.pending_op == int(LocalOp.NOP)) & \
+                            (st2.agents.pending_req == int(MsgType.NOP))
+                retired = outstanding & mshr_free
+                outstanding = outstanding & ~retired
 
-            # ---- compact retirement record (trace mode) -----------------
-            out_idx, retire = c.out_idx, c.retire
-            if collect_trace:
-                # stream index of each in-flight transaction; retiring
-                # lanes stamp the step into their slot's row, everything
-                # else lands in the scratch row T (sliced off on readout).
-                idx_d = jnp.zeros((R, L), jnp.int32).at[
-                    ar[:, None], s_line].add(jnp.where(can, idxc, 0))
-                out_idx = jnp.where(newly, idx_d, c.out_idx)
-                row = jnp.where(retired, out_idx, T)         # [R, L]
-                retire = c.retire.at[row, ar[:, None]].set(t)
+                # ---- compact retirement record (trace mode) -------------
+                out_idx, retire = c.out_idx, c.retire
+                if collect_trace:
+                    # stream index of each in-flight transaction; retiring
+                    # lanes stamp the step into their slot's row, everything
+                    # else lands in the scratch row T (sliced off on readout).
+                    idx_d = jnp.zeros((R, L), jnp.int32).at[
+                        ar[:, None], s_line].add(jnp.where(can, idxc, 0))
+                    out_idx = jnp.where(newly, idx_d, c.out_idx)
+                    row = jnp.where(retired, out_idx, T)         # [R, L]
+                    retire = c.retire.at[row, ar[:, None]].set(t)
 
-            # ---- sojourn + admission-wait histograms (open loop) --------
-            soj = c.soj
-            slot_acc = can & newly[ar[:, None], s_line]      # [R, W]
-            if open_loop:
-                soj_born = jnp.where(newly, soj_d, soj.born)
-                s_lat = t - soj_born                         # [R, L]
-                sb = jnp.searchsorted(soj_edges, s_lat, side="right")
-                hist = soj.hist + ((sb[..., None] == soj_ids) &
-                                   retired[..., None]).sum((0, 1))
-                ab = jnp.searchsorted(soj_edges, t - s_arr, side="right")
-                admit = soj.admit + ((ab[..., None] == soj_ids) &
-                                     slot_acc[..., None]).sum((0, 1))
-                soj = _Soj(born=soj_born, hist=hist.astype(jnp.int32),
-                           admit=admit.astype(jnp.int32))
+                # ---- sojourn + admission-wait histograms (open loop) ----
+                soj = c.soj
+                slot_acc = can & newly[ar[:, None], s_line]      # [R, W]
+                if open_loop:
+                    soj_born = jnp.where(newly, soj_d, soj.born)
+                    s_lat = t - soj_born                         # [R, L]
+                    sb = jnp.searchsorted(soj_edges, s_lat, side="right")
+                    hist = soj.hist + ((sb[..., None] == soj_ids) &
+                                       retired[..., None]).sum((0, 1))
+                    ab = jnp.searchsorted(soj_edges, t - s_arr, side="right")
+                    admit = soj.admit + ((ab[..., None] == soj_ids) &
+                                         slot_acc[..., None]).sum((0, 1))
+                    soj = _Soj(born=soj_born, hist=hist.astype(jnp.int32),
+                               admit=admit.astype(jnp.int32))
 
-            # ---- slide each window past its issued prefix ---------------
-            nop_skip = pending & is_nop
-            if open_loop:   # a NOP slot is consumed at its arrival, not
-                nop_skip = nop_skip & arrived    # before (FIFO stamps)
-            issued = c.issued | slot_acc | nop_skip
-            shift = jnp.cumprod(issued.astype(jnp.int32), axis=1).sum(1)
-            cursor = c.cursor + shift
-            k2 = wr[None, :] + shift[:, None]                # [R, W]
-            # a slot sliding in from past the member's window is FRESH
-            # (born now) — under a fleet the boundary is the member's
-            # width_cap, not the compiled W-max, or masked slots' stale
-            # born stamps would leak into real slots' latency metrics.
-            in_w = (k2 < width_cap) if fleet else (k2 < W)
-            k2c = jnp.minimum(k2, W - 1)
-            issued2 = jnp.where(in_w,
-                                jnp.take_along_axis(issued, k2c, axis=1),
-                                False)
-            slot_born = jnp.where(
-                in_w, jnp.take_along_axis(c.slot_born, k2c, axis=1), t + 1)
+                # ---- slide each window past its issued prefix -----------
+                nop_skip = pending & is_nop
+                if open_loop:   # a NOP slot is consumed at its arrival, not
+                    nop_skip = nop_skip & arrived    # before (FIFO stamps)
+                issued = c.issued | slot_acc | nop_skip
+                shift = jnp.cumprod(issued.astype(jnp.int32), axis=1).sum(1)
+                cursor = c.cursor + shift
+                k2 = wr[None, :] + shift[:, None]                # [R, W]
+                # a slot sliding in from past the member's window is FRESH
+                # (born now) — under a fleet the boundary is the member's
+                # width_cap, not the compiled W-max, or masked slots' stale
+                # born stamps would leak into real slots' latency metrics.
+                in_w = (k2 < width_cap) if fleet else (k2 < W)
+                k2c = jnp.minimum(k2, W - 1)
+                issued2 = jnp.where(in_w,
+                                    jnp.take_along_axis(issued, k2c, axis=1),
+                                    False)
+                slot_born = jnp.where(
+                    in_w, jnp.take_along_axis(c.slot_born, k2c, axis=1), t + 1)
 
             # ---- hardware-style counters fold through the carry ---------
-            lat = t - born
-            waiting = active & ~issued                       # [R, W]
-            head_wait = jnp.where(waiting, t - c.slot_born, 0).max(axis=1)
-            # active = stream unconsumed or engine non-quiescent: the
-            # denominator for sustained rates (the scan's generous drain
-            # tail runs idle steps that must not dilute throughput).
-            step_active = active.any() | busy_flag_mn(st2)
-            ctr = update_counters(c.ctr, st2, retired=retired, lat=lat,
-                                  outstanding=outstanding,
-                                  head_wait=head_wait,
-                                  step_active=step_active,
-                                  backend=kernel_backend)
+            with jax.named_scope("eci.counters"):
+                lat = t - born
+                waiting = active & ~issued                       # [R, W]
+                head_wait = jnp.where(waiting, t - c.slot_born, 0).max(axis=1)
+                # active = stream unconsumed or engine non-quiescent: the
+                # denominator for sustained rates (the scan's generous drain
+                # tail runs idle steps that must not dilute throughput).
+                step_active = active.any() | busy_flag_mn(st2)
+                ctr = update_counters(c.ctr, st2, retired=retired, lat=lat,
+                                      outstanding=outstanding,
+                                      head_wait=head_wait,
+                                      step_active=step_active,
+                                      backend=kernel_backend)
 
             # ---- observability plane (in-scan; compiled in only when
             # ---- an ObserveConfig keys this program) --------------------
             oc = c.obs
             if obs is not None:
-                oc = fold_obs(obs, jnp.asarray(tab_np),
-                              jnp.asarray(start_np), oc, ev, t,
-                              line_filt, type_filt,
-                              newly=newly, born_d=born_d, retired=retired)
+                with jax.named_scope("eci.observe"):
+                    oc = fold_obs(obs, jnp.asarray(tab_np),
+                                  jnp.asarray(start_np), oc, ev, t,
+                                  line_filt, type_filt,
+                                  newly=newly, born_d=born_d, retired=retired)
 
             c2 = _Carry(st=st2, cursor=cursor, issued=issued2,
                         slot_born=slot_born,
@@ -583,52 +589,57 @@ def stream_program(engine: EngineMN, cfg: StreamConfig):
 
 def _run_config(engine: EngineMN, cfg: StreamConfig,
                 st: Optional[EngineMNState]) -> StreamRun:
-    p = _program(engine, cfg)
-    wl, arr, steps = p.wl, p.arr, p.steps
-    T = int(np.asarray(wl.op).shape[0])
-    st0 = engine.init() if st is None else st
-    base_msgs = np.asarray(st0.msg_count, np.int64)
-    base_payload = int(st0.payload_msgs)
-    carry, completed = p.fn(st0, *p.operands)
-    trace = None
-    if cfg.collect_trace:
-        # compact O(T * R) record: the scratch row the non-retiring lanes
-        # scatter into is sliced off; op/line/value come straight from
-        # the workload, which the retire_step array indexes 1:1.
-        trace = RetirementTrace(
-            retire_step=np.asarray(carry.retire)[:-1],
-            op=np.asarray(wl.op),
-            line=np.asarray(wl.line),
-            value=np.asarray(wl.value),
-            n_lines=engine.n_lines,
+    # host spans on the profiler's clock (no-ops unless a trace is
+    # active): what the host does before, at and after the device program.
+    with TraceAnnotation("eci.prepare"):
+        p = _program(engine, cfg)
+        wl, arr, steps = p.wl, p.arr, p.steps
+        T = int(np.asarray(wl.op).shape[0])
+        st0 = engine.init() if st is None else st
+        base_msgs = np.asarray(st0.msg_count, np.int64)
+        base_payload = int(st0.payload_msgs)
+    with TraceAnnotation("eci.dispatch"):
+        carry, completed = p.fn(st0, *p.operands)
+    with TraceAnnotation("eci.readback"):
+        trace = None
+        if cfg.collect_trace:
+            # compact O(T * R) record: the scratch row the non-retiring lanes
+            # scatter into is sliced off; op/line/value come straight from
+            # the workload, which the retire_step array indexes 1:1.
+            trace = RetirementTrace(
+                retire_step=np.asarray(carry.retire)[:-1],
+                op=np.asarray(wl.op),
+                line=np.asarray(wl.line),
+                value=np.asarray(wl.value),
+                n_lines=engine.n_lines,
+            )
+        obs_res = None
+        if cfg.observe is not None:
+            obs_res = finalize_obs(cfg.observe, carry.obs,
+                                   compiled_specs(cfg.observe.specs))
+        soj_hist = admit_hist = None
+        backlog = 0
+        if arr is not None:
+            soj_hist = np.asarray(carry.soj.hist, np.int64)
+            admit_hist = np.asarray(carry.soj.admit, np.int64)
+            # backlog = arrived-but-never-issued ops when the budget ran out:
+            # the cursor counts each remote's consumed prefix; non-contiguous
+            # issued slots still sit in the window flags.
+            arrived_total = int((np.asarray(arr.step) < steps).sum())
+            cur = np.asarray(carry.cursor, np.int64)
+            iss = np.asarray(carry.issued)
+            idx = cur[:, None] + np.arange(int(cfg.width))[None, :]
+            issued_total = int(cur.sum()) + int((iss & (idx < T)).sum())
+            backlog = arrived_total - issued_total
+        return StreamRun(
+            state=carry.st,
+            counters=jax.device_get(carry.ctr),
+            msg_count=np.asarray(carry.st.msg_count, np.int64) - base_msgs,
+            payload_msgs=int(carry.st.payload_msgs) - base_payload,
+            trace=trace,
+            completed=bool(completed),
+            obs=obs_res,
+            sojourn_hist=soj_hist,
+            admit_wait_hist=admit_hist,
+            backlog=backlog,
         )
-    obs_res = None
-    if cfg.observe is not None:
-        obs_res = finalize_obs(cfg.observe, carry.obs,
-                               compiled_specs(cfg.observe.specs))
-    soj_hist = admit_hist = None
-    backlog = 0
-    if arr is not None:
-        soj_hist = np.asarray(carry.soj.hist, np.int64)
-        admit_hist = np.asarray(carry.soj.admit, np.int64)
-        # backlog = arrived-but-never-issued ops when the budget ran out:
-        # the cursor counts each remote's consumed prefix; non-contiguous
-        # issued slots still sit in the window flags.
-        arrived_total = int((np.asarray(arr.step) < steps).sum())
-        cur = np.asarray(carry.cursor, np.int64)
-        iss = np.asarray(carry.issued)
-        idx = cur[:, None] + np.arange(int(cfg.width))[None, :]
-        issued_total = int(cur.sum()) + int((iss & (idx < T)).sum())
-        backlog = arrived_total - issued_total
-    return StreamRun(
-        state=carry.st,
-        counters=jax.device_get(carry.ctr),
-        msg_count=np.asarray(carry.st.msg_count, np.int64) - base_msgs,
-        payload_msgs=int(carry.st.payload_msgs) - base_payload,
-        trace=trace,
-        completed=bool(completed),
-        obs=obs_res,
-        sojourn_hist=soj_hist,
-        admit_wait_hist=admit_hist,
-        backlog=backlog,
-    )

@@ -55,6 +55,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.engine_mn import make_engine_mn_state
 from .config import FleetConfig
@@ -92,132 +93,136 @@ def run_fleet(fleet: FleetConfig) -> List[StreamRun]:
     out of the stacked carry.  See the module docstring for the
     bit-identity contract.
     """
-    members = fleet.members
-    engines = [e.build() for e, _ in members]
-    e0, s0 = members[0]
-    R_max = max(e.remotes for e, _ in members)
-    W_max = max(s.width for _, s in members)
-    steps = fleet_steps(fleet)
-    mesh_n = int(fleet.mesh_devices)
-    if mesh_n:
-        avail = len(jax.devices())
-        if mesh_n > avail:
-            platform = jax.devices()[0].platform
-            hint = (f"on CPU expose more with XLA_FLAGS="
-                    f"--xla_force_host_platform_device_count={mesh_n} "
-                    f"before importing jax" if platform == "cpu" else
-                    f"run on a {platform} host with at least {mesh_n} "
-                    f"chips")
-            raise ValueError(
-                f"mesh_devices={mesh_n} but only {avail} {platform} "
-                f"device(s) are visible — {hint}")
+    # host spans on the profiler's clock, as in ``run_stream``.
+    with TraceAnnotation("eci.prepare"):
+        members = fleet.members
+        engines = [e.build() for e, _ in members]
+        e0, s0 = members[0]
+        R_max = max(e.remotes for e, _ in members)
+        W_max = max(s.width for _, s in members)
+        steps = fleet_steps(fleet)
+        mesh_n = int(fleet.mesh_devices)
+        if mesh_n:
+            avail = len(jax.devices())
+            if mesh_n > avail:
+                platform = jax.devices()[0].platform
+                hint = (f"on CPU expose more with XLA_FLAGS="
+                        f"--xla_force_host_platform_device_count={mesh_n} "
+                        f"before importing jax" if platform == "cpu" else
+                        f"run on a {platform} host with at least {mesh_n} "
+                        f"chips")
+                raise ValueError(
+                    f"mesh_devices={mesh_n} but only {avail} {platform} "
+                    f"device(s) are visible — {hint}")
 
-    # materialize + subset-check each member's workload at its own
-    # [T, R_m], then pad to the fleet plane with NOP columns.
-    wls = []
-    for eng, (e, s) in zip(engines, members):
-        wl = s.workload.materialize(e.remotes, e.lines)
-        if not eng.subset.check_workload(np.asarray(wl.op),
-                                         n_remotes=e.remotes):
-            raise ValueError(
-                f"fleet member workload outside subset "
-                f"'{eng.subset.name}' guarantee (allowed ops: "
-                f"{sorted(eng.subset.allowed_ops(e.remotes))})")
-        wls.append(wl)
-    T = int(np.asarray(wls[0].op).shape[0])
+        # materialize + subset-check each member's workload at its own
+        # [T, R_m], then pad to the fleet plane with NOP columns.
+        wls = []
+        for eng, (e, s) in zip(engines, members):
+            wl = s.workload.materialize(e.remotes, e.lines)
+            if not eng.subset.check_workload(np.asarray(wl.op),
+                                             n_remotes=e.remotes):
+                raise ValueError(
+                    f"fleet member workload outside subset "
+                    f"'{eng.subset.name}' guarantee (allowed ops: "
+                    f"{sorted(eng.subset.allowed_ops(e.remotes))})")
+            wls.append(wl)
+        T = int(np.asarray(wls[0].op).shape[0])
 
-    def pad_cols(a):
-        a = np.asarray(a)
-        out = np.zeros((T, R_max), a.dtype)
-        out[:, :a.shape[1]] = a
-        return out
+        def pad_cols(a):
+            a = np.asarray(a)
+            out = np.zeros((T, R_max), a.dtype)
+            out[:, :a.shape[1]] = a
+            return out
 
-    # under a mesh the member axis pads to a device multiple by repeating
-    # the last member; pad rows compute independently and are never read
-    # back.
-    n_real = len(members)
-    rows = list(range(n_real))
-    if mesh_n and n_real % mesh_n:
-        rows += [n_real - 1] * (mesh_n - n_real % mesh_n)
+        # under a mesh the member axis pads to a device multiple by repeating
+        # the last member; pad rows compute independently and are never read
+        # back.
+        n_real = len(members)
+        rows = list(range(n_real))
+        if mesh_n and n_real % mesh_n:
+            rows += [n_real - 1] * (mesh_n - n_real % mesh_n)
 
-    # every per-member operand is stacked on the host and placed straight
-    # onto its member's device ("fleet"-sharded under a mesh) — no stacked
-    # copy ever lands on one device first.
-    sharding = None
-    if mesh_n:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        sharding = NamedSharding(fleet_mesh(mesh_n), P("fleet"))
+        # every per-member operand is stacked on the host and placed straight
+        # onto its member's device ("fleet"-sharded under a mesh) — no stacked
+        # copy ever lands on one device first.
+        sharding = None
+        if mesh_n:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            sharding = NamedSharding(fleet_mesh(mesh_n), P("fleet"))
 
-    def stack(per_member):
-        return jax.device_put(
-            np.stack([np.asarray(per_member[i]) for i in rows]), sharding)
+        def stack(per_member):
+            return jax.device_put(
+                np.stack([np.asarray(per_member[i]) for i in rows]), sharding)
 
-    wl_op = stack([pad_cols(w.op) for w in wls])
-    wl_line = stack([pad_cols(w.line) for w in wls])
-    wl_value = stack([pad_cols(w.value) for w in wls])
-    delays = stack([eng.delays for eng in engines])
-    credits = stack([eng.credits for eng in engines])
-    width_cap = stack([np.int32(s.width) for _, s in members])
-    home_group = stack([np.int32(e.homes) for e, _ in members])
-    home_bw_t = stack([np.int32(e.home_bw) for e, _ in members])
+        wl_op = stack([pad_cols(w.op) for w in wls])
+        wl_line = stack([pad_cols(w.line) for w in wls])
+        wl_value = stack([pad_cols(w.value) for w in wls])
+        delays = stack([eng.delays for eng in engines])
+        credits = stack([eng.credits for eng in engines])
+        width_cap = stack([np.int32(s.width) for _, s in members])
+        home_group = stack([np.int32(e.homes) for e, _ in members])
+        home_bw_t = stack([np.int32(e.home_bw) for e, _ in members])
 
-    # fresh R-max states (padded remotes start — and stay — idle), made
-    # on the device(s) that run them.
-    def fresh_states():
-        st1 = make_engine_mn_state(
-            jnp.zeros((e0.lines, e0.block), jnp.float32), R_max,
-            packed=e0.packed)
-        return jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a, (len(rows),) + a.shape), st1)
+        # fresh R-max states (padded remotes start — and stay — idle), made
+        # on the device(s) that run them.
+        def fresh_states():
+            st1 = make_engine_mn_state(
+                jnp.zeros((e0.lines, e0.block), jnp.float32), R_max,
+                packed=e0.packed)
+            return jax.tree_util.tree_map(
+                lambda a: jnp.broadcast_to(a, (len(rows),) + a.shape), st1)
 
-    st = jax.jit(fresh_states, **(
-        {"out_shardings": sharding} if sharding else {}))()
+        st = jax.jit(fresh_states, **(
+            {"out_shardings": sharding} if sharding else {}))()
 
-    # the multi-home plane is EMULATED (home_group), so the program keys
-    # on the flat layout; shared_credits/obs/open-loop are out of fleet
-    # scope by FleetConfig validation.
-    fn = _jitted_stream(engines[0].subset.name, s0.collect_trace, W_max,
-                        False, 1, 0, None, False, 0, 0,
-                        engines[0].kernel_backend, True, mesh_n)
-    tsteps = jnp.arange(steps, dtype=jnp.int32)
-    if mesh_n:
-        # the sharded entry point takes no filter/arrival operands (they
-        # are out of fleet scope and shard_map specs cover real args).
-        carry, completed = fn(st, wl_op, wl_line, wl_value, tsteps,
-                              delays, credits,
-                              width_cap, home_group, home_bw_t)
-    else:
-        carry, completed = fn(st, wl_op, wl_line, wl_value, tsteps,
-                              delays, credits, None, None, None,
-                              width_cap, home_group, home_bw_t)
-
-    completed = np.asarray(completed)
-    retire = np.asarray(carry.retire) if s0.collect_trace else None
-    ctr_all = jax.device_get(carry.ctr)
-    msg_all = np.asarray(carry.st.msg_count, np.int64)
-    pay_all = np.asarray(carry.st.payload_msgs)
-    runs = []
-    for i, (eng, (e, s), wl) in enumerate(zip(engines, members, wls)):
-        R_m = e.remotes
-        ctr = jax.tree_util.tree_map(lambda x: x[i], ctr_all)
-        # the three per-remote counter planes carry padded rows (all
-        # zero except lat_hist's never-touched rows) — slice them off so
-        # the record is indistinguishable from the solo run's.
-        ctr = ctr._replace(lat_hist=ctr.lat_hist[:R_m],
-                           max_wait=ctr.max_wait[:R_m],
-                           retired=ctr.retired[:R_m])
-        trace = None
-        if s0.collect_trace:
-            trace = RetirementTrace(
-                retire_step=retire[i][:-1, :R_m],
-                op=np.asarray(wl.op), line=np.asarray(wl.line),
-                value=np.asarray(wl.value), n_lines=e.lines)
-        runs.append(StreamRun(
-            state=jax.tree_util.tree_map(lambda x: _member(x, i), carry.st),
-            counters=ctr,
-            msg_count=msg_all[i],
-            payload_msgs=int(pay_all[i]),
-            trace=trace,
-            completed=bool(completed[i]),
-        ))
-    return runs
+        # the multi-home plane is EMULATED (home_group), so the program keys
+        # on the flat layout; shared_credits/obs/open-loop are out of fleet
+        # scope by FleetConfig validation.
+        fn = _jitted_stream(engines[0].subset.name, s0.collect_trace, W_max,
+                            False, 1, 0, None, False, 0, 0,
+                            engines[0].kernel_backend, True, mesh_n)
+        tsteps = jnp.arange(steps, dtype=jnp.int32)
+    with TraceAnnotation("eci.dispatch"):
+        if mesh_n:
+            # the sharded entry point takes no filter/arrival operands (they
+            # are out of fleet scope and shard_map specs cover real args).
+            carry, completed = fn(st, wl_op, wl_line, wl_value, tsteps,
+                                  delays, credits,
+                                  width_cap, home_group, home_bw_t)
+        else:
+            carry, completed = fn(st, wl_op, wl_line, wl_value, tsteps,
+                                  delays, credits, None, None, None,
+                                  width_cap, home_group, home_bw_t)
+    with TraceAnnotation("eci.readback"):
+        completed = np.asarray(completed)
+        retire = np.asarray(carry.retire) if s0.collect_trace else None
+        ctr_all = jax.device_get(carry.ctr)
+        msg_all = np.asarray(carry.st.msg_count, np.int64)
+        pay_all = np.asarray(carry.st.payload_msgs)
+        runs = []
+        for i, (eng, (e, s), wl) in enumerate(zip(engines, members, wls)):
+            R_m = e.remotes
+            ctr = jax.tree_util.tree_map(lambda x: x[i], ctr_all)
+            # the three per-remote counter planes carry padded rows (all
+            # zero except lat_hist's never-touched rows) — slice them off so
+            # the record is indistinguishable from the solo run's.
+            ctr = ctr._replace(lat_hist=ctr.lat_hist[:R_m],
+                               max_wait=ctr.max_wait[:R_m],
+                               retired=ctr.retired[:R_m])
+            trace = None
+            if s0.collect_trace:
+                trace = RetirementTrace(
+                    retire_step=retire[i][:-1, :R_m],
+                    op=np.asarray(wl.op), line=np.asarray(wl.line),
+                    value=np.asarray(wl.value), n_lines=e.lines)
+            runs.append(StreamRun(
+                state=jax.tree_util.tree_map(lambda x: _member(x, i),
+                                             carry.st),
+                counters=ctr,
+                msg_count=msg_all[i],
+                payload_msgs=int(pay_all[i]),
+                trace=trace,
+                completed=bool(completed[i]),
+            ))
+        return runs
