@@ -112,6 +112,23 @@ def make_counters(n_remotes: int) -> Counters:
     )
 
 
+def bucket_counts(x: jnp.ndarray, mask: jnp.ndarray, edges: np.ndarray,
+                  axis) -> jnp.ndarray:
+    """Histogram of the integer samples ``x`` where ``mask`` holds, over
+    the sorted ``edges`` (bucket i holds x in [edge[i-1], edge[i]), the
+    last bucket is the overflow), summed over ``axis``; the bucket axis
+    is last (traced).
+
+    The bucket is ``searchsorted(edges, x, side="right")`` taken as
+    compares: no per-element gather on a TPU.  XLA fuses the one-hot
+    compare into the reduction, so no [..., n_buckets] plane is
+    materialised."""
+    edges = jnp.asarray(edges)
+    bucket = (x[..., None] >= edges).sum(-1)
+    onehot = bucket[..., None] == jnp.arange(edges.shape[0] + 1)
+    return (onehot & mask[..., None]).sum(axis=axis)
+
+
 def update_counters(ctr: Counters, st, *, retired: jnp.ndarray,
                     lat: jnp.ndarray, outstanding: jnp.ndarray,
                     head_wait: jnp.ndarray,
@@ -135,11 +152,7 @@ def update_counters(ctr: Counters, st, *, retired: jnp.ndarray,
         hist = ctr.lat_hist + _kops.lat_hist(
             lat, retired, tuple(int(e) for e in LAT_EDGES))
     else:
-        # searchsorted(side="right") over sorted integer edges, as
-        # compares: no per-element gather on a TPU.
-        bucket = (lat[..., None] >= jnp.asarray(LAT_EDGES)).sum(-1)
-        onehot = bucket[..., None] == jnp.arange(N_LAT_BUCKETS)
-        hist = ctr.lat_hist + (onehot & retired[..., None]).sum(axis=1)
+        hist = ctr.lat_hist + bucket_counts(lat, retired, LAT_EDGES, axis=1)
 
     # the starvation bound: worst of (retired latency, in-flight wait,
     # head-of-stream wait) — a starved request never retires, so the live

@@ -64,7 +64,8 @@ from .arrivals import ArrivalSchedule, check_schedule
 from .config import (AdmissionConfig, ArrivalSpec, StreamConfig,
                      WorkloadSpec)
 from .counters import (Counters, N_SOJ_BUCKETS, RetirementTrace,
-                       SOJOURN_EDGES, make_counters, update_counters)
+                       SOJOURN_EDGES, bucket_counts, make_counters,
+                       update_counters)
 from .observe import (ObserveConfig, ObsResult, _encoded_tables,
                       compiled_specs, finalize_obs, fold_obs,
                       make_obs_carry)
@@ -209,8 +210,6 @@ def _jitted_stream(subset_name: str, collect_trace: bool, width: int,
         wr = jnp.arange(W)
         zb = jnp.zeros((L,), bool)
         zwv = jnp.zeros((L, B), dt)
-        soj_edges = jnp.asarray(SOJOURN_EDGES)
-        soj_ids = jnp.arange(N_SOJ_BUCKETS)
 
         def body(c, t):
             # ---- fetch each remote's issue window -----------------------
@@ -322,12 +321,10 @@ def _jitted_stream(subset_name: str, collect_trace: bool, width: int,
                 if open_loop:
                     soj_born = jnp.where(newly, soj_d, soj.born)
                     s_lat = t - soj_born                         # [R, L]
-                    sb = jnp.searchsorted(soj_edges, s_lat, side="right")
-                    hist = soj.hist + ((sb[..., None] == soj_ids) &
-                                       retired[..., None]).sum((0, 1))
-                    ab = jnp.searchsorted(soj_edges, t - s_arr, side="right")
-                    admit = soj.admit + ((ab[..., None] == soj_ids) &
-                                         slot_acc[..., None]).sum((0, 1))
+                    hist = soj.hist + bucket_counts(
+                        s_lat, retired, SOJOURN_EDGES, axis=(0, 1))
+                    admit = soj.admit + bucket_counts(
+                        t - s_arr, slot_acc, SOJOURN_EDGES, axis=(0, 1))
                     soj = _Soj(born=soj_born, hist=hist.astype(jnp.int32),
                                admit=admit.astype(jnp.int32))
 

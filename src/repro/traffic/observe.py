@@ -65,7 +65,7 @@ from ..core.engine_mn import StepEvents
 from ..core.messages import MsgType
 from ..core.tracing import (N_SYMBOLS, SPECS, CompiledSpec, TraceBuffer,
                             compile_spec, symbol_id, symbol_id_name)
-from .counters import LAT_EDGES, N_LAT_BUCKETS
+from .counters import LAT_EDGES, N_LAT_BUCKETS, bucket_counts
 
 #: Attribution phase rows of ``phase_hist`` (shared LAT_EDGES buckets).
 PHASES = ("queue", "service", "home", "fanout")
@@ -279,10 +279,7 @@ def _hist_add(rows, masks, dts):
     and ``dts`` are [k, ...]; returns rows + per-row bucket counts.
     (One-hot + reduce beats a scatter-add here: CPU XLA serializes
     scatter, while the [k, n, NB] bool reduction vectorizes.)"""
-    bucket = jnp.searchsorted(jnp.asarray(LAT_EDGES), dts, side="right")
-    onehot = bucket[..., None] == jnp.arange(N_LAT_BUCKETS)
-    k = masks.shape[0]
-    add = (onehot & masks[..., None]).reshape(k, -1, N_LAT_BUCKETS).sum(1)
+    add = bucket_counts(dts, masks, LAT_EDGES, axis=tuple(range(1, dts.ndim)))
     return rows + add.astype(jnp.int32)
 
 

@@ -16,6 +16,7 @@ import pytest
 from repro.traffic import (AdmissionConfig, ArrivalSpec, EngineConfig,
                            FleetConfig, StreamConfig, WorkloadSpec,
                            run_fleet, run_stream, stream_program)
+from repro.traffic.counters import N_SOJ_BUCKETS
 from repro.traffic.observe import ObserveConfig
 
 R, L = 4, 64
@@ -38,15 +39,21 @@ CONFIGS = {
 
 
 @pytest.fixture(scope="module")
-def op_names():
-    """``op_name`` metadata of each configuration's compiled program."""
+def hlo():
+    """Each configuration's compiled program, as HLO text."""
     eng = EngineConfig(remotes=R, lines=L).build()
     out = {}
     for name, cfg in CONFIGS.items():
         fn, operands = stream_program(eng, cfg)
-        text = fn.lower(*operands).compile().as_text()
-        out[name] = set(re.findall(r'op_name="([^"]*)"', text))
+        out[name] = fn.lower(*operands).compile().as_text()
     return out
+
+
+@pytest.fixture(scope="module")
+def op_names(hlo):
+    """``op_name`` metadata of each configuration's compiled program."""
+    return {name: set(re.findall(r'op_name="([^"]*)"', text))
+            for name, text in hlo.items()}
 
 
 def _scopes(names):
@@ -66,13 +73,29 @@ def test_compiled_program_carries_every_phase(op_names, name):
     assert scopes == want
 
 
-def test_admission_and_sojourn_only_in_the_open_loop(op_names):
+def _sojourn_fold(text):
+    """Some reduce to the ``s32[N_SOJ_BUCKETS]`` sojourn / admission-wait
+    histogram sits inside ``eci.retire``."""
+    return re.search(rf'= s32\[{N_SOJ_BUCKETS}\]\S* reduce\(.*op_name='
+                     r'"[^"]*eci\.retire/reduce_sum"', text) is not None
+
+
+def test_admission_and_sojourn_only_in_the_open_loop(op_names, hlo):
     """The admission sort is issue work and the sojourn histograms are
     retirement work, and only the open-loop program has either."""
     for name, names in op_names.items():
         is_open = name == "open_admission"
         assert _under(names, "eci.issue", "argsort") == is_open, name
-        assert _under(names, "eci.retire", "searchsorted") == is_open, name
+        assert _sojourn_fold(hlo[name]) == is_open, name
+
+
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+@pytest.mark.parametrize("scope", ["eci.retire", "eci.counters"])
+def test_histogram_folds_bucket_without_searchsorted(op_names, name, scope):
+    """The histogram folds bucket by compares: a ``searchsorted`` is a
+    per-element gather on a TPU, and under the sojourn fold it took most
+    of an open-loop step."""
+    assert not _under(op_names[name], scope, "searchsorted")
 
 
 def test_ranking_counts_as_credit_rank_inside_the_fan_out(op_names):
