@@ -16,9 +16,22 @@ point, which compiles the stream program and the benchmark's read-back
 program; then points run back to back for about ``seconds``.
 After the window, every point is compared with the plain reference
 (``checks``).
+
+A configuration file that holds a ``"fleet"`` group beside ``"engine"``
+(``{"remotes": [...]}``) is a sweep run as one program: a point is then
+one ``run_fleet`` call whose members are the engine at each of
+``fleet.remotes`` (``engine.remotes`` is the largest), one member per
+chip of the cell's ``chips``, or all on one chip where it asks for one
+(``FleetTarget``).  Each member draws
+its inputs from a generator of its own, its final state is read back on
+the chip that holds it, and it is compared with the reference as a
+one-engine point is; the point is correct when every member is.  The
+metrics read a fleet point as one call: its ops are all members' ops,
+its steps the one budget every member scans.
 """
 from __future__ import annotations
 
+import functools
 import json
 import shutil
 import sys
@@ -132,7 +145,10 @@ def check_interface() -> None:
 
 def _extractor():
     """The benchmark's read-back program: the touched lines of the final
-    state, and what lies off them."""
+    state, and what lies off them.  Where ``real`` (the member's remote
+    count) is given, the state is a fleet member's, padded to the fleet's
+    largest member, and every non-zero entry of a padded remote's row
+    counts as a stray one."""
     import jax
     import jax.numpy as jnp
 
@@ -147,12 +163,24 @@ def _extractor():
         return jnp.where(off.reshape(shape), jnp.abs(a.astype(jnp.float32)),
                          0).max()
 
+    def padded(st, real):
+        rows = [st.agents, st.ch_req, st.ch_resp, st.ch_hreq, st.ch_hresp]
+        if st.dir.view.dtype == jnp.int8:   # dense [R, L] planes
+            rows += [st.dir.view, st.hreq_pending]
+        pad = jnp.arange(st.agents.remote_state.shape[0]) >= real
+        return sum(((a != 0) & pad.reshape((-1,) + (1,) * (a.ndim - 1))
+                    ).sum() for a in jax.tree_util.tree_leaves(rows))
+
     @jax.jit
-    def extract(st, idx, touched):
+    def extract(st, idx, touched, real=None):
         d, ag = st.dir, st.agents
         off = ~touched
         f32 = lambda a: a.astype(jnp.float32)
         dense_view = d.view.dtype == jnp.int8
+        strays = (stray(d.home_state, 0, off) + stray(d.view, 1, off)
+                  + stray(ag.remote_state, 1, off))
+        if real is not None:
+            strays = strays + padded(st, real)
         return {
             "home_state": jnp.take(d.home_state, idx, axis=0),
             "view": jnp.take(d.view, idx, axis=1) if dense_view else None,
@@ -160,9 +188,7 @@ def _extractor():
             "cache": f32(jnp.take(ag.cache, idx, axis=1)),
             "home_buf": f32(jnp.take(d.home_buf, idx, axis=0)),
             "backing": f32(jnp.take(d.backing, idx, axis=0)),
-            "stray": (stray(d.home_state, 0, off)
-                      + stray(d.view, 1, off)
-                      + stray(ag.remote_state, 1, off)),
+            "stray": strays,
             "stray_data": jnp.maximum(jnp.maximum(
                 off_max(ag.cache, 1, off), off_max(d.home_buf, 0, off)),
                 off_max(d.backing, 0, off)),
@@ -191,6 +217,7 @@ class Target:
                 shared_credits=cfg.shared_credits, n_homes=cfg.homes,
                 home_bw=cfg.home_bw, kernel_backend=eng.kernel_backend,
                 packed=cfg.packed)
+        self.traffic = traffic
         self.remotes, self.lines = cfg.remotes, cfg.lines
         adm = traffic.get("admission")
         self._stream = lambda **kw: StreamConfig(
@@ -199,29 +226,130 @@ class Target:
             collect_trace=True, **kw)
         self._extract = _extractor()
 
-    def run(self, inputs: traffic_gen.Inputs):
+    def inputs(self, seed: int, point: int) -> List[traffic_gen.Inputs]:
+        return [traffic_gen.generate(self.traffic, self.remotes, self.lines,
+                                     seed, point)]
+
+    def run(self, inputs: List[traffic_gen.Inputs]):
         from repro.traffic import ArrivalSchedule, Workload, run_stream
-        arr = (None if inputs.arrival is None
-               else ArrivalSchedule(inputs.arrival))
-        return run_stream(self.engine, self._stream(
-            workload=Workload(inputs.op, inputs.line, inputs.value),
-            arrivals=arr))
+        (inp,) = inputs
+        arr = (None if inp.arrival is None
+               else ArrivalSchedule(inp.arrival))
+        return [run_stream(self.engine, self._stream(
+            workload=Workload(inp.op, inp.line, inp.value), arrivals=arr))]
 
     def extract(self, state, inputs: traffic_gen.Inputs):
         """Device arrays of the final state's touched lines (blocks until
         they are computed, so the state can go)."""
-        import jax
-        lines = np.unique(inputs.line[inputs.op != traffic_gen.NOP])
-        idx = np.full(inputs.op.size, lines[0] if lines.size else 0,
-                      np.int32)
-        idx[:lines.size] = lines
-        touched = np.zeros(self.lines, bool)
-        touched[lines] = True
-        return lines, jax.block_until_ready(
-            self._extract(state, idx, touched))
+        return _extract(self._extract, state, inputs, self.lines)
 
 
-class Point(NamedTuple):
+def _extract(fn, state, inputs: traffic_gen.Inputs, n_lines: int,
+             real: Optional[int] = None):
+    import jax
+    lines = np.unique(inputs.line[inputs.op != traffic_gen.NOP])
+    idx = np.full(inputs.op.size, lines[0] if lines.size else 0, np.int32)
+    idx[:lines.size] = lines
+    touched = np.zeros(n_lines, bool)
+    touched[lines] = True
+    return lines, jax.block_until_ready(fn(
+        state, idx, touched, None if real is None else np.int32(real)))
+
+
+class FleetTarget:
+    """A sweep as one program: the configuration's engine at each of
+    ``fleet.remotes`` remotes, run as the members of one ``run_fleet``
+    call per point, sharded over ``mesh_devices`` devices (0: all on
+    one).  Each member's generated arrays reach ``run_fleet`` through the
+    recipe it reads (``given_workload``).  ``dtype`` makes the fleet's
+    fresh state, and so every member's line data, in another precision
+    (the control): ``run_fleet`` builds that state itself, in float32."""
+
+    def __init__(self, config: dict, traffic: dict, dtype=None,
+                 mesh_devices: int = 0):
+        from repro.traffic import EngineConfig, StreamConfig
+        fleet, engine = config["fleet"], config["engine"]
+        self.member_remotes = [int(r) for r in fleet["remotes"]]
+        if max(self.member_remotes) != engine["remotes"]:
+            raise ValueError(f"engine.remotes ({engine['remotes']}) must "
+                             f"be the largest of fleet.remotes "
+                             f"{self.member_remotes}")
+        self.engines = [EngineConfig(**dict(engine, remotes=r))
+                        for r in self.member_remotes]
+        self.mesh_devices = int(mesh_devices)
+        self.traffic, self.dtype = traffic, dtype
+        self.lines = int(engine["lines"])
+        self._stream = lambda wl: StreamConfig(
+            workload=wl, width=int(traffic.get("width", 1)), steps=0,
+            collect_trace=True)
+        self._extract = _extractor()
+
+    def inputs(self, seed: int, point: int) -> List[traffic_gen.Inputs]:
+        return [traffic_gen.generate(self.traffic, r, self.lines, seed,
+                                     point, member=m)
+                for m, r in enumerate(self.member_remotes)]
+
+    def run(self, inputs: List[traffic_gen.Inputs]):
+        from unittest import mock
+
+        import jax.numpy as jnp
+        from repro.traffic import FleetConfig, Workload, fleet, run_fleet
+        if any(i.arrival is not None for i in inputs):
+            raise ValueError("a fleet runs closed-loop traffic only")
+        members = tuple(
+            (e, self._stream(given_workload()(
+                ops=i.op.shape[0], given=Workload(i.op, i.line, i.value))))
+            for e, i in zip(self.engines, inputs))
+        config = FleetConfig(members=members,
+                             mesh_devices=self.mesh_devices)
+        if self.dtype is None:
+            return run_fleet(config)
+        real = fleet.make_engine_mn_state
+        with mock.patch.object(
+                fleet, "make_engine_mn_state",
+                lambda backing, *a, **k: real(
+                    jnp.asarray(backing, self.dtype), *a, **k)):
+            return run_fleet(config)
+
+    def extract(self, state, inputs: traffic_gen.Inputs):
+        """As ``Target.extract``, on the chip that holds the member's
+        state; its padded remotes' rows count under ``stray``."""
+        return _extract(self._extract, state, inputs, self.lines,
+                        real=inputs.op.shape[1])
+
+
+@functools.lru_cache(maxsize=None)
+def given_workload():
+    """The class of a fleet member's generated arrays, in the form
+    ``run_fleet`` takes a member's workload: a ``WorkloadSpec`` whose
+    ``ops`` is T and whose ``materialize`` gives the arrays (``run_fleet``
+    takes no concrete ``Workload``)."""
+    import dataclasses
+
+    from repro.traffic import WorkloadSpec
+
+    @dataclasses.dataclass(frozen=True)
+    class GivenWorkload(WorkloadSpec):
+        given: object = None
+
+        def materialize(self, n_remotes: int, n_lines: int):
+            return self.given
+    return GivenWorkload
+
+
+def make_target(cell: Cell, dtype=None):
+    """The target a cell's configuration asks for: a fleet where it holds
+    a ``"fleet"`` group, sharded over the cell's chips, else one
+    engine."""
+    if "fleet" not in cell.config:
+        return Target(cell.config, cell.traffic, dtype)
+    return FleetTarget(cell.config, cell.traffic, dtype,
+                       mesh_devices=cell.chips if cell.chips > 1 else 0)
+
+
+class Member(NamedTuple):
+    """One engine's share of a point: its inputs and what it returned."""
+
     inputs: traffic_gen.Inputs
     completed: bool
     retired: int
@@ -233,31 +361,55 @@ class Point(NamedTuple):
     extracted: dict
 
 
-def run_point(target: Target, inputs: traffic_gen.Inputs) -> Point:
+class Point(NamedTuple):
+    """One call of the program: one member, or a fleet's members."""
+
+    members: List[Member]
+
+    @property
+    def retired(self) -> int:
+        return sum(m.retired for m in self.members)
+
+    @property
+    def steps(self) -> int:
+        """The steps the call scanned (every member scans the same)."""
+        return self.members[0].steps
+
+    @property
+    def active_steps(self) -> int:
+        return sum(m.active_steps for m in self.members)
+
+
+def run_point(target, inputs: List[traffic_gen.Inputs]) -> Point:
     from jax.profiler import TraceAnnotation
     with TraceAnnotation(trace_reduce.POINT_SPAN):
-        run = target.run(inputs)
+        runs = target.run(inputs)
+    members = []
     with TraceAnnotation("bench.extract"):
-        lines, ex = target.extract(run.state, inputs)
-    ctr = run.counters
-    return Point(inputs, bool(run.completed), int(np.sum(ctr.retired)),
-                 int(ctr.steps), int(ctr.active_steps),
-                 np.asarray(run.trace.retire_step), np.asarray(run.msg_count),
-                 lines, ex)
+        for run, inp in zip(runs, inputs):
+            lines, ex = target.extract(run.state, inp)
+            ctr = run.counters
+            members.append(Member(
+                inp, bool(run.completed), int(np.sum(ctr.retired)),
+                int(ctr.steps), int(ctr.active_steps),
+                np.asarray(run.trace.retire_step),
+                np.asarray(run.msg_count), lines, ex))
+    return Point(members)
 
 
-def point_output(p: Point) -> checks.PointOutput:
+def member_output(m: Member) -> checks.PointOutput:
     import jax
-    ex = jax.device_get(p.extracted)
-    k = p.lines.size
+    ex = jax.device_get(m.extracted)
+    k, R = m.lines.size, m.inputs.op.shape[1]
     f64 = lambda a: np.asarray(a, np.float64)
     return checks.PointOutput(
-        completed=p.completed, retired=p.retired, steps=p.steps,
-        retire_step=p.retire_step, msg_count=p.msg_count, lines=p.lines,
+        completed=m.completed, retired=m.retired, steps=m.steps,
+        retire_step=m.retire_step, msg_count=m.msg_count, lines=m.lines,
         home_state=np.asarray(ex["home_state"])[:k],
-        view=None if ex["view"] is None else np.asarray(ex["view"])[:, :k],
-        remote_state=np.asarray(ex["remote_state"])[:, :k],
-        cache=f64(ex["cache"])[:, :k], home_buf=f64(ex["home_buf"])[:k],
+        view=(None if ex["view"] is None
+              else np.asarray(ex["view"])[:R, :k]),
+        remote_state=np.asarray(ex["remote_state"])[:R, :k],
+        cache=f64(ex["cache"])[:R, :k], home_buf=f64(ex["home_buf"])[:k],
         backing=f64(ex["backing"])[:k], stray=int(ex["stray"]),
         stray_data=float(ex["stray_data"]), illegal=int(ex["illegal"]))
 
@@ -278,7 +430,7 @@ def _profile_options():
     return opts
 
 
-def measure(target: Target, cell: Cell, seed: int, seconds: float,
+def measure(target, cell: Cell, seed: int, seconds: float,
             trace: bool, clock: CompileClock) -> Window:
     """Points back to back for about ``seconds``: the first point's time
     sets how many fill the window (at least one), so the window is within
@@ -296,9 +448,7 @@ def measure(target: Target, cell: Cell, seed: int, seconds: float,
             count = None
             while count is None or len(points) < count:
                 with TraceAnnotation("bench.generate"):
-                    inputs = traffic_gen.generate(
-                        cell.traffic, target.remotes, target.lines, seed,
-                        len(points))
+                    inputs = target.inputs(seed, len(points))
                 points.append(run_point(target, inputs))
                 if count is None:
                     first = time.perf_counter() - start
@@ -354,7 +504,7 @@ def peak_bytes(devices) -> int:
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              devices, t0: float,
-             target_factory: Optional[Callable[[], Target]] = None,
+             target_factory: Optional[Callable[[], object]] = None,
              hbm_bytes: Optional[int] = None, warm_up: bool = True) -> dict:
     """Set-up, window and check of one run; returns the result line.
     ``warm_up=False`` skips the warm-up point, for a process that has
@@ -362,24 +512,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     clock = CompileClock()
     check_interface()
     reference = load_module("references", cell.config["reference"])
-    target = (target_factory or (lambda: Target(cell.config,
-                                                cell.traffic)))()
+    target = (target_factory or (lambda: make_target(cell)))()
     if warm_up:   # compiles the stream and the read-back programs
-        run_point(target, traffic_gen.generate(
-            cell.traffic, target.remotes, target.lines, seed, -1))
+        run_point(target, target.inputs(seed, -1))
     setup_s = time.perf_counter() - t0
     win = measure(target, cell, seed, seconds, trace, clock)
     peak = peak_bytes(devices)
-    outputs = [point_output(p) for p in win.points]
+    outputs = [[member_output(m) for m in p.members] for p in win.points]
     del target
-    numbers = [checks.compare_point(p.inputs, o, reference)
-               for p, o in zip(win.points, outputs)]
-    total = checks.combine(numbers)
+    numbers = [[checks.compare_point(m.inputs, o, reference)
+                for m, o in zip(p.members, out)]
+               for p, out in zip(win.points, outputs)]
+    total = checks.combine([n for point in numbers for n in point])
     dev = devices[0]
     result = {
         "correct": checks.verdict(total),
         "attempted": len(win.points),
-        "failed": sum(not checks.verdict(n) for n in numbers),
+        "failed": sum(not all(map(checks.verdict, n)) for n in numbers),
         "metrics": (per_layer(cell, win) if trace else
                     end_to_end(cell, win, setup_s, peak)),
         "device": {"platform": dev.platform, "kind": dev.device_kind,

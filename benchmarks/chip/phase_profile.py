@@ -55,9 +55,8 @@ def profile(cell: harness.Cell, seed: int, seconds: float,
     clock = harness.CompileClock()
     harness.check_interface()
     target = harness.Target(cell.config, cell.traffic)
-    warm = traffic_gen.generate(cell.traffic, target.remotes, target.lines,
-                                seed, -1)
-    harness.run_point(target, warm)
+    (warm,) = target.inputs(seed, -1)
+    harness.run_point(target, [warm])
     setup_s = time.perf_counter() - t0
     t = time.perf_counter()
     phase_of = phase_reduce.hlo_phases(stream_hlo(target, warm))
@@ -73,9 +72,7 @@ def profile(cell: harness.Cell, seed: int, seconds: float,
             count = None
             while count is None or len(points) < count:
                 with TraceAnnotation("bench.generate"):
-                    inputs = traffic_gen.generate(
-                        cell.traffic, target.remotes, target.lines, seed,
-                        len(points))
+                    inputs = target.inputs(seed, len(points))
                 points.append(harness.run_point(target, inputs))
                 if count is None:
                     count = max(1, round(seconds
@@ -119,6 +116,10 @@ def main() -> None:
 
     import run
     cell = harness.load_cell(args.workload)
+    if "fleet" in cell.config:
+        sys.exit("phase_profile.py: the program gives the stream program "
+                 "of run_stream only (driver.stream_program), not a "
+                 "fleet's")
     run.require_chips(cell.chips)
     harness.enable_compile_cache()
     print(json.dumps(profile(cell, args.seed, args.seconds, T0)),

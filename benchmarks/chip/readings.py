@@ -6,7 +6,8 @@
 
 In one process: a run of the program on each seed, then a run of the
 control on each control seed (the program's own bfloat16 line-data path,
-the precision below the configuration's float32), each a window of
+the precision below the configuration's float32; for a fleet, its fresh
+state made in bfloat16), each a window of
 ``--seconds`` as the benchmark measures it.  Prints one JSON line per
 run with every number the check compares.  The benchmark's own runs do
 not run this.
@@ -45,8 +46,7 @@ def main() -> None:
             res = harness.run_cell(
                 cell, seed, args.seconds, False, devices,
                 time.perf_counter(), warm_up=i == 0,
-                target_factory=lambda: harness.Target(
-                    cell.config, cell.traffic, dtype=dtype))
+                target_factory=lambda: harness.make_target(cell, dtype))
             print(json.dumps({"cell": cell.name, "run": kind, "seed": seed,
                               "correct": res["correct"],
                               "points": res["attempted"],

@@ -4,7 +4,11 @@ Each run drives the harness past its look for a chip, at R=4 agents and
 64 lines on the CPU: a sound run is correct; the control (the program's
 own bfloat16 line-data path, one precision below the configuration's
 float32) is not; nor is a run with the timed path broken underneath, by
-each fault a one-chip cell can have.
+each fault a cell can have.  The fleet path, which no cell of the
+benchmark takes yet, runs as ``<closed cell>+fleet``: that cell's engine
+swept over members of 1 to 4 agents as one ``run_fleet`` call on one
+device.  A fleet's members are independent, with no exchange between
+chips to leave out.
 """
 import json
 import os
@@ -21,9 +25,13 @@ SEED = 2 ** 35 + 17
 
 
 def tiny_cell(name):
-    cell = harness.load_cell(name)
+    base, fleet, _ = name.partition("+fleet")
+    cell = harness.load_cell(base)
     config = dict(cell.config, engine=dict(cell.config["engine"], remotes=4,
                                            lines=64, block=4))
+    if fleet:
+        config["fleet"] = {"remotes": [1, 2, 3, 4]}
+        cell = cell._replace(name=name, chips=1)
     # 4 agents hold few ops: at least 3 each, so that a point has stores.
     traffic = dict(cell.traffic,
                    ops_per_remote=max(3, cell.traffic["ops_per_remote"]))
@@ -55,21 +63,23 @@ def plant(monkeypatch):
 
 CELLS = [w["name"] for w in json.loads(harness.BENCHMARK.read_text())[
     "workloads"]]
+FLEETS = [n + "+fleet" for n in CELLS
+          if not harness.load_cell(n).traffic.get("arrivals")]
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CELLS + FLEETS)
 def test_sound_run_is_correct(name):
     res = run(tiny_cell(name))
     assert res["correct"], res["checks"]
     assert res["attempted"] == 1 and res["failed"] == 0
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CELLS + FLEETS)
 def test_control_lower_precision_fails(name):
     import jax.numpy as jnp
     cell = tiny_cell(name)
-    res = run(cell, target_factory=lambda: harness.Target(
-        cell.config, cell.traffic, dtype=jnp.bfloat16))
+    res = run(cell, target_factory=lambda: harness.make_target(
+        cell, jnp.bfloat16))
     assert not res["correct"]
     assert res["checks"]["data"]["value"] > 0
     assert res["checks"]["messages"]["value"] == 0
@@ -127,4 +137,25 @@ def test_fault_fails(plant, fault, number):
     plant(fault)
     res = run(tiny_cell(CELLS[0]))
     assert not res["correct"]
+    assert res["checks"][number]["value"] > res["checks"][number]["limit"]
+
+
+def _pad(real):
+    """A remote row that the fleet pads a member with, left non-zero."""
+    def step(base, tables, st, op, val, *a, **k):
+        st2, out = real(base, tables, st, op, val, *a, **k)
+        ag = st2.agents
+        return st2._replace(agents=ag._replace(
+            pending_req=ag.pending_req.at[-1, -1].set(1))), out
+    return step
+
+
+@pytest.mark.parametrize("name", FLEETS)
+@pytest.mark.parametrize("fault,number", [
+    (_stuck, "incomplete"), (_half, "incomplete"), (_value, "data"),
+    (_pad, "states")], ids=["stuck", "half", "value", "pad"])
+def test_fault_fails_a_fleet(plant, fault, number, name):
+    plant(fault)
+    res = run(tiny_cell(name))
+    assert not res["correct"] and res["failed"] == res["attempted"] == 1
     assert res["checks"][number]["value"] > res["checks"][number]["limit"]

@@ -153,8 +153,13 @@ def test_poisson_window_offers_its_rate():
     assert steps.mean() == pytest.approx((window - 1) / 2, rel=0.05)
 
 
+def _member(retired, steps, active):
+    return harness.Member(None, True, retired, steps, active, None, None,
+                          None, None)
+
+
 def _points(retired, steps, active):
-    return [harness.Point(None, True, r, s, a, None, None, None, None)
+    return [harness.Point([_member(r, s, a)])
             for r, s, a in zip(retired, steps, active)]
 
 

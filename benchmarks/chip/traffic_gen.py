@@ -19,7 +19,10 @@ An arrivals module has ``generate(rng, T, R, **params)`` returning the
 latest arrival, since the step budget is a shape of the program.
 
 Everything is drawn from ``numpy.random.default_rng([seed, point])``,
-which takes seeds of any size: the same seed gives the same inputs.
+which takes seeds of any size: the same seed gives the same inputs.  A
+fleet's members each draw from a generator of their own,
+``default_rng([seed, point, member + 1])``, so no two members of a point get
+the same inputs, and a one-engine cell's inputs are those it always had.
 """
 from __future__ import annotations
 
@@ -46,9 +49,15 @@ class Inputs(NamedTuple):
     arrival: Optional[np.ndarray]   # [T, R] int32 arrival step, or None
 
 
-def point_rng(seed: int, point: int) -> np.random.Generator:
-    """The generator of one point; ``point`` -1 is the warm-up point."""
-    return np.random.default_rng([int(seed), int(point) + 1])
+def point_rng(seed: int, point: int,
+              member: Optional[int] = None) -> np.random.Generator:
+    """The generator of one point (of one fleet member, where ``member``
+    is given); ``point`` -1 is the warm-up point."""
+    key = [int(seed), int(point) + 1]
+    # member + 1: a key that ends in 0 draws as if the 0 were not there,
+    # so member 0 would draw the one-engine point's inputs.
+    return np.random.default_rng(key if member is None
+                                 else key + [int(member) + 1])
 
 
 def store_values(rng, T, R) -> np.ndarray:
@@ -65,9 +74,10 @@ def _params(group: dict):
 
 
 def generate(traffic: dict, n_remotes: int, n_lines: int, seed: int,
-             point: int) -> Inputs:
-    """The inputs of point ``point`` of a run with ``seed``."""
-    rng = point_rng(seed, point)
+             point: int, member: Optional[int] = None) -> Inputs:
+    """The inputs of point ``point`` of a run with ``seed`` (of fleet
+    member ``member``, where it is given)."""
+    rng = point_rng(seed, point, member)
     T = int(traffic["ops_per_remote"])
     kind, params = _params(traffic["workload"])
     op, line = _module("patterns", kind).generate(
